@@ -15,6 +15,9 @@ def main() -> None:
     ap.add_argument("--repeats", type=int, default=3)
     args = ap.parse_args()
 
+    from repro.device import use_compile_cache
+
+    use_compile_cache()
     from . import (bench_cache, bench_fusion, bench_online, bench_resilience,
                    bench_rewrite, bench_serve, bench_shard, bench_tiling,
                    bench_transfer,

@@ -10,9 +10,11 @@ from repro.cloudsc.erosion import physical_inputs
 from repro.cloudsc.scheme import scheme_inputs
 from repro.core import Schedule, compile_jax, normalize
 from repro.core.util import time_fn
+from repro.device import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     nproma, klev = 128, 137
     p = erosion_program(nproma, klev)
     pn = normalize(p)

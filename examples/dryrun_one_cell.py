@@ -17,9 +17,11 @@ def main() -> None:
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
+    from repro.device import use_compile_cache
     from repro.launch.dryrun import lower_cell
     from repro.launch.roofline import analyse_cell, param_counts, advice
 
+    use_compile_cache()
     rec = lower_cell(args.arch, args.shape, args.multi_pod)
     if rec["status"] != "ok":
         print(rec)

@@ -9,6 +9,9 @@ from repro.core import (
     normalize,
 )
 from repro.core.scheduler import random_inputs
+from repro.device import use_compile_cache
+
+use_compile_cache()
 
 # -- 1. author a loop nest (the paper's Fig. 1 "gemm_2": bad loop order) -----
 NI, NJ, NK = 256, 256, 256

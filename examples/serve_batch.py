@@ -11,11 +11,13 @@ import numpy as np
 import jax
 
 from repro.configs import get_config
+from repro.device import use_compile_cache
 from repro.models import model as M
 from repro.serve import ServeConfig, ServingEngine
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = get_config("mixtral-8x7b").reduced()  # tiny MoE+SWA decoder on CPU
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(

@@ -11,6 +11,7 @@ from dataclasses import replace
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
+from repro.device import use_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.train_loop import Trainer, TrainerConfig
 
@@ -22,6 +23,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm_100m")
     args = ap.parse_args()
+    use_compile_cache()
 
     # ~109M params: 12 layers x d768 of the minicpm family (CPU-trainable;
     # ~300 steps takes ~20-30 min on a 1-core container)

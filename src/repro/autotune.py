@@ -77,6 +77,7 @@ from dataclasses import dataclass, field
 from multiprocessing import get_context
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from .core import Daisy, Program, TuningDatabase, fingerprint, program_fingerprint
@@ -241,6 +242,16 @@ class PoolStall(RuntimeError):
     """No task completed within the progress timeout — workers presumed hung."""
 
 
+def _require_host_pool(what: str) -> None:
+    """Refuse a spawn pool on a TPU: a chip serves one process at a time,
+    and this process already holds it, so the workers could not reach it."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} starts worker processes, but this process holds the TPU "
+            "and a chip serves one process at a time; tune in-process "
+            "instead (jobs=1, or SearchSupervisor mode='sync'/'thread')")
+
+
 def run_supervised(
     tasks: list[dict],
     jobs: int,
@@ -261,6 +272,8 @@ def run_supervised(
     :func:`online_search_task` for deployment searches) — it must be a
     module-level callable so the spawn pool can pickle it.
     """
+    if jobs > 1:
+        _require_host_pool(f"run_supervised(jobs={jobs})")
     results: list[dict] = []
     quarantined: dict[str, str] = {}
     policies: dict[str, RestartPolicy] = {}
@@ -640,6 +653,8 @@ class SearchSupervisor:
     ):
         if mode not in ("sync", "thread", "spawn"):
             raise ValueError(f"mode must be sync|thread|spawn, got {mode!r}")
+        if mode == "spawn":
+            _require_host_pool("SearchSupervisor(mode='spawn')")
         self.db = db
         self.backend = backend
         self.policy = policy or SwapPolicy()

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..device import pallas_backend
 from .dependence import EQ, nest_direction_vectors
 from .ir import (
     Access,
@@ -153,7 +154,7 @@ class Schedule:
     vec_budget: int = 1 << 22  # max materialized elements per computation
     pallas_gemm: bool = False  # route GEMM idiom to the Pallas MXU kernel
     tile: tuple[int, int, int] | None = None  # Pallas GEMM block sizes
-    interpret: bool = True  # Pallas interpret mode (CPU container)
+    interpret: bool | None = None  # Pallas interpret mode; None: off on a TPU only
     pallas_nest: bool = False  # grid-tiled Pallas for parallel nests
     pallas_reduce: bool = False  # grid-tiled Pallas for reduction nests
     nest_tile: tuple[int, ...] | None = None  # trailing-axis tiles (+red last)
@@ -161,6 +162,14 @@ class Schedule:
     scan: bool = True  # lax.scan recurrences (canonical mode)
     vmem_budget: int = 1 << 23  # tiling planner working-set budget (bytes)
     shard_axis: str | None = None  # mesh axis for the partition planner
+
+    @property
+    def interpret_kernels(self) -> bool:
+        """Whether Pallas kernels run in the interpreter: ``interpret`` when
+        set, else everywhere but on a TPU."""
+        if self.interpret is not None:
+            return self.interpret
+        return pallas_backend() != "pallas"
 
 
 # Trace-time lowering counters (tests assert which path actually fired).
@@ -738,15 +747,16 @@ class _NestEmitter:
             return None  # partial-cover writes take the generic path
         contrib = None
         if self.s.pallas_gemm and len(operands) == 2:
-            # canonical 2-operand contraction -> Pallas MXU kernel
-            try:
-                from ..kernels import ops as kops
+            # canonical 2-operand contraction -> Pallas MXU kernel; a
+            # contraction the GEMM cannot express takes jnp.einsum
+            from ..kernels import ops as kops
 
+            try:
                 contrib = kops.einsum2(
                     subs[0], subs[1], out_sub, operands[0], operands[1],
-                    tile=self.s.tile, interpret=self.s.interpret,
+                    tile=self.s.tile, interpret=self.s.interpret_kernels,
                 )
-            except Exception:
+            except kops.NotAContraction:
                 contrib = None
         if contrib is None:
             spec = ",".join(subs) + "->" + out_sub

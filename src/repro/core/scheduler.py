@@ -26,6 +26,7 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import numpy as np
 
+from ..device import pallas_backend
 from .cache import CacheStats, CompilationCache
 from .codegen import compile_jax
 from .database import TuningDatabase, default_pretuned_path
@@ -144,7 +145,6 @@ class Daisy:
     def __init__(
         self,
         db: TuningDatabase | None = None,
-        interpret: bool = True,
         cache: CompilationCache | None = None,
         fuse: bool = True,
         rewrite: bool = True,
@@ -157,12 +157,12 @@ class Daisy:
         * ``'xla'``             — rewrite pallas recipes onto their XLA
                                   equivalents (einsum / vectorize); no Pallas
                                   kernels are built at all,
-        * ``'pallas_interpret'``— Pallas kernels in interpret mode (CPU
-                                  correctness container; the default),
-        * ``'pallas'``          — compiled Pallas (the TPU deploy target).
+        * ``'pallas_interpret'``— Pallas kernels in interpret mode (the
+                                  default off a TPU: CPU correctness tests),
+        * ``'pallas'``          — compiled Pallas (the default on a TPU).
 
-        ``interpret`` is kept for backward compatibility; passing ``backend``
-        overrides it.
+        ``None`` takes the platform's Pallas backend
+        (``repro.device.pallas_backend``).
 
         ``mesh`` turns on the sharded execution path: ``compile`` routes the
         normalized program through the partition planner
@@ -171,13 +171,12 @@ class Daisy:
         falls back to replication wherever the dependence oracle vetoes.  A
         recipe's ``parallelize`` knob overrides the default axis per nest.
         """
-        if backend is not None:
-            if backend not in ("xla", "pallas_interpret", "pallas"):
-                raise ValueError(f"unknown backend {backend!r}")
-            interpret = backend != "pallas"
-        self.backend = backend or ("pallas_interpret" if interpret else "pallas")
+        backend = backend or pallas_backend()
+        if backend not in ("xla", "pallas_interpret", "pallas"):
+            raise ValueError(f"unknown backend {backend!r}")
+        self.backend = backend
+        self.interpret = backend != "pallas"
         self.db = db if db is not None else TuningDatabase()
-        self.interpret = interpret
         self.fuse = fuse
         self.rewrite = rewrite
         self.mesh = mesh

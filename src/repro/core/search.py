@@ -42,7 +42,7 @@ def default_recipe_for(idiom: IdiomMatch) -> Recipe:
 
 
 def schedule_from_recipe(
-    recipe: Recipe, interpret: bool = True, shard_axis: str | None = None
+    recipe: Recipe, interpret: bool | None = None, shard_axis: str | None = None
 ) -> Schedule:
     """Recipe -> Schedule.  ``shard_axis`` is the scheduler-level default
     mesh axis (``Daisy.shard_axis`` under a mesh); the recipe's own
@@ -132,7 +132,7 @@ def measure_recipe(
     inputs: Mapping[str, np.ndarray],
     recipe: Recipe,
     repeats: int = 3,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> float:
     """Wall time (us) of one nest lowered under ``recipe``; inf on failure.
 
@@ -161,7 +161,7 @@ def evolve_recipe(
     rng_seed: int = 0,
     reseed_pool: list[Recipe] | None = None,
     resolve: Callable[[Recipe], Recipe] | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     repeats: int = 3,
     deadline_s: float | None = None,
 ) -> tuple[Recipe, float]:

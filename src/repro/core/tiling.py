@@ -152,40 +152,50 @@ def _loop_bounds(nest: Node) -> dict[str, tuple[int, int]]:
     return out
 
 
-def _align_floor(axis_pos: int, n_axes: int, trip: int) -> tuple[int, int]:
-    """(alignment, floor) for auto-chosen tiles: lane axis multiples of 128,
-    sublane axis multiples of 8, outer axes unconstrained."""
-    if axis_pos == n_axes - 1:
-        unit = LANE
-    elif axis_pos == n_axes - 2:
-        unit = SUBLANE
-    else:
-        unit = 1
-    return unit, min(unit, max(1, trip))
+def _align_units(comps: Sequence[Computation]) -> dict[str, int]:
+    """Tile alignment per iterator for auto-chosen tiles: 128 where it
+    subscripts the last (lane) dimension of any accessed array, 8 where it
+    subscripts a second-to-last (sublane) dimension, else 1 — and 1024
+    (8 x 128) for the only dimension of a rank-1 array, which Mosaic tiles
+    whole.  A block dim must be a multiple of its unit or span the whole
+    array dimension."""
+    units: dict[str, int] = {}
+    for c in comps:
+        for a in (c.write,) + c.reads:
+            rank = len(a.index)
+            for d, ix in enumerate(a.index):
+                for it in ix.iterators():
+                    u = (SUBLANE * LANE if rank == 1 else LANE if d == rank - 1
+                         else SUBLANE if d == rank - 2 else 1)
+                    units[it] = max(units.get(it, 1), u)
+    return units
 
 
 def _shrink_to_budget(
     tiles: list[int],
     trips: list[int],
+    units: list[int],
     block_bytes,
     budget: int,
 ) -> list[int]:
-    """Halve the largest tile (keeping VPU alignment) until the estimated
-    working set fits; stop at the alignment floors."""
+    """Halve the largest tile (keeping its alignment unit) until the
+    estimated working set fits; stop at the floors (one unit, or the whole
+    trip when that is shorter)."""
     n = len(tiles)
+
+    def halved(k: int) -> int:
+        floor = min(units[k], max(1, trips[k]))
+        return max(floor, -(-(tiles[k] // 2) // units[k]) * units[k])
+
     while block_bytes(tiles) > budget:
         best, best_gain = -1, 0
         for k in range(n):
-            unit, floor = _align_floor(k, n, trips[k])
-            if tiles[k] <= floor:
-                continue
-            new = max(floor, -(-(tiles[k] // 2) // unit) * unit)
-            if new < tiles[k] and tiles[k] - new > best_gain:
-                best, best_gain = k, tiles[k] - new
+            gain = tiles[k] - halved(k)
+            if gain > best_gain:
+                best, best_gain = k, gain
         if best < 0:
             break  # at the floors everywhere: accept best effort
-        unit, floor = _align_floor(best, n, trips[best])
-        tiles[best] = max(floor, -(-(tiles[best] // 2) // unit) * unit)
+        tiles[best] = halved(best)
     return tiles
 
 
@@ -270,7 +280,10 @@ def plan_nest_tiling(
             p = dict(zip(par_its + ([grid_red_it] if grid_red_it else []), ts))
             return _estimate_vmem(program, comps, p, trips, red_order)
 
-        all_tiles = _shrink_to_budget(all_tiles, all_trips, est, vmem_budget)
+        unit_of = _align_units(comps)
+        all_units = [unit_of.get(it, 1)
+                     for it in par_its + ([grid_red_it] if grid_red_it else [])]
+        all_tiles = _shrink_to_budget(all_tiles, all_trips, all_units, est, vmem_budget)
         par_tiles = all_tiles[: len(par_its)]
         if grid_red_it:
             red_tile = all_tiles[-1]
@@ -363,19 +376,36 @@ def _estimate_vmem(
     trips: Mapping[str, int],
     red_order: Sequence[str],
 ) -> int:
-    """Bytes resident per grid step: one block per distinct access map plus
-    the old-content alias of each output and the reduction accumulator."""
+    """Bytes resident in VMEM per grid step, as the TPU lays them out.
+
+    One block per distinct access map plus the old-content alias and the
+    output block of each write, each double-buffered by the Pallas
+    pipeline; for a reduction also the accumulator and the in-kernel value
+    over the whole slab (every axis at its tile) that is reduced into it.
+    A block keeps the array's rank (a constant subscript is a dimension of
+    extent 1) and is stored in (8, 128) tiles: its last dimension pads to
+    128 lanes and the one before to 8 sublanes (a rank-1 block to 8 x 128),
+    so a trailing extent-1 dimension costs 128 elements."""
     itemsize = 4
     inner = set(red_order[:-1])
 
-    def block_elems(a: Access) -> int:
-        n = 1
+    def block_bytes(a: Access) -> int:
+        dims = []
         for ix in a.index:
             its = ix.iterators()
             if not its:
-                continue
-            it = its[0]
-            n *= trips[it] if it in inner else tile_of.get(it, trips[it])
+                dims.append(1)
+            else:
+                it = its[0]
+                dims.append(trips[it] if it in inner else tile_of.get(it, trips[it]))
+        if dims:
+            last = SUBLANE * LANE if len(dims) == 1 else LANE
+            dims[-1] = -(-dims[-1] // last) * last
+        if len(dims) > 1:
+            dims[-2] = -(-dims[-2] // SUBLANE) * SUBLANE
+        n = itemsize
+        for d in dims:
+            n *= d
         return n
 
     seen: set[tuple] = set()
@@ -386,9 +416,12 @@ def _estimate_vmem(
             if key in seen:
                 continue
             seen.add(key)
-            total += block_elems(a) * itemsize
-        # output block + accumulator scratch for reductions
-        total += block_elems(c.write) * itemsize
+            total += 2 * block_bytes(a)
+        total += 2 * block_bytes(c.write)  # output block
         if c.accumulate is not None and red_order:
-            total += block_elems(c.write) * itemsize
+            total += block_bytes(c.write)  # accumulator scratch
+            slab = itemsize
+            for it in c.iterators():
+                slab *= trips[it] if it in inner else tile_of.get(it, trips[it])
+            total += slab
     return total

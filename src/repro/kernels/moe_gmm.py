@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_d: int):
     d = pl.program_id(3)
@@ -68,7 +66,7 @@ def grouped_matmul(
         out_specs=pl.BlockSpec((1, bc, bf), lambda ei, i, j, k: (ei, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

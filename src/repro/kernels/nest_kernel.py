@@ -22,8 +22,8 @@
   lanes keep old content (assignments) or contribute the accumulate's
   neutral element (reductions).
 
-Everything is validated on CPU with ``interpret=True`` against the
-``execute_numpy`` oracle; ``interpret=False`` targets TPU (grid dims are
+Everything is validated on CPU in interpret mode against the
+``execute_numpy`` oracle; on a TPU the kernel compiles (grid dims are
 declared parallel/arbitrary accordingly).
 """
 from __future__ import annotations
@@ -40,7 +40,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.codegen import _ACC_INIT, _ACC_REDUCE, _combine
 from ..core.ir import Access, Computation, Node, Program
 from ..core.tiling import TilePlan, TilingError, plan_nest_tiling
-from .compat import CompilerParams
 
 # trace-time lowering counters (tests assert the Pallas path actually ran)
 EMITTED = {"pallas_nest": 0, "pallas_reduce": 0}
@@ -64,7 +63,7 @@ def emit_nest(
 
     emitter = _KernelBuilder(program, plan, env,
                              unroll=max(1, int(schedule.unroll)),
-                             interpret=schedule.interpret)
+                             interpret=schedule.interpret_kernels)
     out_env = emitter.build()
     EMITTED["pallas_nest" if plan.kind == "parallel" else "pallas_reduce"] += 1
     return out_env
@@ -249,7 +248,7 @@ class _KernelBuilder:
             out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
             scratch_shapes=scratch,
             input_output_aliases=aliases,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=tuple(semantics)),
             interpret=self.interpret,
         )(*in_views)
@@ -301,7 +300,9 @@ class _KernelBuilder:
             oi = written.index(comp.write.array)
 
             if plan.kind == "reduce":
-                self._emit_reduce(comp, val, mask, wdims, gids, outs[oi], acc_ref)
+                old_ref = ins[op_of[(comp.write.array, comp.write.index)]]
+                self._emit_reduce(comp, val, mask, wdims, gids, old_ref,
+                                  outs[oi], acc_ref)
                 continue
 
             old = load(comp.write, used)
@@ -312,7 +313,10 @@ class _KernelBuilder:
                 outs[oi].dtype)
             slab_env[comp.write.array] = (comp.write.index, merged)
 
-    def _emit_reduce(self, comp, val, mask, wdims, gids, o_ref, acc_ref):
+    def _emit_reduce(self, comp, val, mask, wdims, gids, old_ref, o_ref, acc_ref):
+        # the old content comes from the aliased input block: on a TPU an
+        # output block is never loaded from HBM, so reading o_ref would see
+        # whatever an earlier block left in that VMEM buffer
         plan = self.plan
         op = comp.accumulate
         neutral = _ACC_INIT[op]
@@ -351,5 +355,5 @@ class _KernelBuilder:
 
         @pl.when(k_red == n_red - 1)
         def _done():
-            o_ref[...] = _combine(op, o_ref[...],
+            o_ref[...] = _combine(op, old_ref[...],
                                   acc_ref[...]).astype(o_ref.dtype)

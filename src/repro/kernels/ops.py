@@ -77,13 +77,17 @@ def rmsnorm(x, gamma, *, eps=1e-6, backend=None):
 # ---------------------------------------------------------------------------
 # daisy codegen hook: 2-operand einsum -> Pallas GEMM
 # ---------------------------------------------------------------------------
+class NotAContraction(ValueError):
+    """The einsum is not a clean 2-operand contraction ``einsum2`` lowers."""
+
+
 def einsum2(sub_a: str, sub_b: str, sub_out: str, a, b, *, tile=None,
             interpret: bool = True):
     """Lower a clean 2-operand contraction to the tiled GEMM kernel.
 
     Only handles the no-batch-dim case (every letter is either contracted or
-    appears in the output exactly once); anything else raises so the caller
-    falls back to jnp.einsum.
+    appears in the output exactly once); anything else raises
+    ``NotAContraction`` so the caller falls back to jnp.einsum.
     """
     letters = set(sub_a) | set(sub_b)
     contracted = [l for l in letters if l in sub_a and l in sub_b and l not in sub_out]
@@ -95,7 +99,7 @@ def einsum2(sub_a: str, sub_b: str, sub_out: str, a, b, *, tile=None,
         or sorted(sub_out) != sorted(kept_a + kept_b)
         or not contracted
     ):
-        raise ValueError("not a clean 2-operand contraction")
+        raise NotAContraction(f"{sub_a},{sub_b}->{sub_out}")
 
     # move contracted letters last in a, first in b; flatten to 2-D
     perm_a = [sub_a.index(l) for l in kept_a] + [sub_a.index(l) for l in contracted]

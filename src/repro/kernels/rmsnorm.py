@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -47,7 +45,7 @@ def rmsnorm(
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, g2)
     return out[:r]

@@ -1,29 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import: jax locks the device
-# count at first initialization.  512 host devices back both the single-pod
-# (16x16) and multi-pod (2x16x16) production meshes.
-
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from functools import partial  # noqa: E402
-from pathlib import Path  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from ..configs import ARCHS, SHAPES, get_config, shape_applicable  # noqa: E402
-from ..core.cache import fingerprint_obj  # noqa: E402
-from ..models import model as M  # noqa: E402
-from ..optim.adamw import AdamWConfig, adamw_init  # noqa: E402
-from ..train.train_loop import make_train_step  # noqa: E402
-from .mesh import dp_axes, make_production_mesh, set_mesh  # noqa: E402
-from .sharding import batch_specs, param_specs, replicated, state_specs  # noqa: E402
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces the compiled artifact's
@@ -38,6 +12,32 @@ decode_32k / long_500k lower serve (decode_step) with a materialized-shape
 KV cache/state.  long_500k cells exist only for sub-quadratic archs
 (DESIGN.md §Arch-applicability); the others record status='skipped'.
 """
+import argparse
+import json
+import os
+import re
+import time
+import traceback
+from functools import partial
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ..configs import ARCHS, SHAPES, get_config, shape_applicable
+from ..core.cache import fingerprint_obj
+from ..models import model as M
+from ..optim.adamw import AdamWConfig, adamw_init
+from ..train.train_loop import make_train_step
+from .mesh import dp_axes, make_production_mesh, set_mesh
+from .sharding import batch_specs, param_specs, replicated, state_specs
+
+# 512 host devices back both the single-pod (16x16) and multi-pod (2x16x16)
+# production meshes.  ``main`` sets the flag before jax initializes a backend;
+# importing this module leaves the process's devices alone.
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
@@ -229,8 +229,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, opt_cfg=None,
     mem = compiled.memory_analysis()
     print(mem)  # proves it fits (bytes per device)
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax <= 0.4.x returns [dict]
-        cost = cost[0] if cost else None
     print({k: cost.get(k) for k in ("flops", "bytes accessed")} if cost else cost)
     coll = collective_bytes(compiled.as_text())
 
@@ -268,6 +266,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, opt_cfg=None,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = HOST_DEVICES_FLAG
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -1,11 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
-import json  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from pathlib import Path  # noqa: E402
-
 """§Perf hillclimbing driver — the three chosen cells, per the assignment:
 
   1. xlstm-350m    x train_4k  — worst roofline fraction (TP overhead swamps
@@ -20,6 +12,10 @@ collective bytes / memory compared against the base cell (per-body HLO is a
 valid A/B because the loop structure is unchanged).  Results land in
 hillclimb_out/ and are summarized in EXPERIMENTS.md §Perf.
 """
+import json
+import os
+import traceback
+from pathlib import Path
 
 CELLS = [
     ("xlstm-350m", "train_4k", "dp_only"),
@@ -32,7 +28,9 @@ CELLS = [
 
 
 def main() -> None:
-    from .dryrun import lower_cell
+    from .dryrun import HOST_DEVICES_FLAG, lower_cell
+
+    os.environ.setdefault("XLA_FLAGS", HOST_DEVICES_FLAG)
 
     out = Path("hillclimb_out")
     out.mkdir(exist_ok=True)

@@ -10,21 +10,30 @@ collective (optim/compression.py).
 The axes generalize: any (pod, data, model) product works, which is the
 1000+-node posture — scale `pod` out over DCN, keep `model` inside the ICI
 domain.
+
+Every axis is ``AxisType.Auto``: the model code states layouts with
+``with_sharding_constraint`` and committed parameter shardings and lets the
+partitioner propagate the rest.  (``jax.make_mesh``'s default, ``Explicit``,
+would make gathers, ``dynamic_update_slice`` and unpad slices on sharded
+operands type errors.)
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests use small host-device meshes, e.g. (2, 4))."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
+    """Arbitrary mesh (tests use small host-device meshes, e.g. (2, 4));
+    ``devices`` picks a subset (default: the first ``prod(shape)``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:
@@ -33,6 +42,5 @@ def dp_axes(mesh) -> tuple[str, ...]:
 
 
 def set_mesh(mesh):
-    """``with set_mesh(mesh):`` on any jax: ``jax.set_mesh`` where it exists,
-    else the Mesh object itself (a context manager on jax <= 0.4.x)."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    """``with set_mesh(mesh):`` — the ambient mesh for jit and sharding hints."""
+    return jax.set_mesh(mesh)

@@ -30,10 +30,12 @@ def main() -> None:
     args = ap.parse_args()
 
     from ..configs import get_config
+    from ..device import use_compile_cache
     from ..data.pipeline import DataConfig
     from ..optim.adamw import AdamWConfig
     from ..train.train_loop import Trainer, TrainerConfig
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -20,6 +20,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..configs.base import ModelConfig
@@ -34,17 +35,12 @@ Params = dict[str, Any]
 def constrain(x: jax.Array, *entries):
     """with_sharding_constraint that degrades gracefully: axes missing from
     the active mesh or non-dividing dims are dropped; no-op without a mesh.
-    Model code can therefore state its preferred layout unconditionally."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
+    Model code can therefore state its preferred layout unconditionally.
+    Any other sharding error raises."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        return x
-    try:
-        sizes = dict(mesh.shape)
-    except Exception:
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    sizes = dict(mesh.shape)
     clean = []
     for d, e in enumerate(entries):
         if e is None or d >= x.ndim:
@@ -60,21 +56,23 @@ def constrain(x: jax.Array, *entries):
             clean.append(None)
     if all(c is None for c in clean):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*clean)
-        )
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, jax.sharding.PartitionSpec(*clean))
 
 
 # ---------------------------------------------------------------------------
 # init helpers
 # ---------------------------------------------------------------------------
+def normal(key, shape) -> jax.Array:
+    """Standard-normal float32 draws.  The barrier keeps XLA from folding a
+    caller's scale into the sampler's own constants, so a jitted init draws
+    bit for bit what eager execution draws."""
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape))
+
+
 def _dense_init(key, shape, dtype, scale=None):
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     s = scale if scale is not None else 1.0 / math.sqrt(fan_in)
-    return (jax.random.normal(key, shape) * s).astype(dtype)
+    return (normal(key, shape) * s).astype(dtype)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -265,7 +263,8 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> Params:
         "x_proj": _dense_init(ks[2], (din, dt_rank + 2 * n), dtype),
         "dt_proj": _dense_init(ks[3], (dt_rank, din), dtype),
         "dt_bias": jnp.full((din,), -2.0, dtype),  # softplus -> small dt
-        "A_log": jnp.log(jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32), (din, 1))),
+        # a host constant: XLA's constant folding would round log differently
+        "A_log": jnp.asarray(np.tile(np.log(np.arange(1, n + 1, dtype=np.float32)), (din, 1))),
         "Dskip": jnp.ones((din,), dtype),
         "out_proj": _dense_init(ks[4], (din, d), dtype),
     }

@@ -54,64 +54,55 @@ def _init_block(key, cfg: ModelConfig, kind: str, use_moe: bool, dt) -> Params:
     return p
 
 
-def _stack(trees: list[Params]) -> Params:
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
-
-
+@partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Random parameters for ``cfg`` from ``key``.
+
+    Each layer stack is one ``lax.map`` of the block init over its layer
+    keys, under jit: only the stacked tree materialises on the device (one
+    copy of the weights, never a per-layer list beside it), one layer's
+    float32 draws are live at a time, and the program compiles once per
+    block kind rather than once per layer.
+    """
     dt = _dtype(cfg)
     keys = jax.random.split(key, cfg.n_layers + cfg.enc_layers + 4)
     p: Params = {
-        "embed": (jax.random.normal(keys[-1], (cfg.vocab, cfg.d_model)) * 0.02).astype(dt),
+        "embed": (L.normal(keys[-1], (cfg.vocab, cfg.d_model)) * 0.02).astype(dt),
         "final_norm": jnp.ones((cfg.d_model,), dt),
     }
     if not cfg.tie_embeddings:
-        p["lm_head"] = (
-            jax.random.normal(keys[-2], (cfg.d_model, cfg.vocab)) / math.sqrt(cfg.d_model)
-        ).astype(dt)
+        # a multiply, not a divide: XLA rewrites a division by a constant
+        # under jit, which would round differently from eager execution
+        p["lm_head"] = (L.normal(keys[-2], (cfg.d_model, cfg.vocab))
+                        * (1.0 / math.sqrt(cfg.d_model))).astype(dt)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        blocks = [
-            _init_block(keys[l], cfg, "attn", cfg.layer_is_moe(l), dt)
-            for l in range(cfg.n_layers)
-        ]
-        # homogeneity check: scan needs identical treedefs
-        p["layers"] = _stack(blocks)
+        # scan needs identical treedefs: every layer is layer 0's kind
+        p["layers"] = jax.lax.map(
+            lambda k: _init_block(k, cfg, "attn", cfg.layer_is_moe(0), dt),
+            keys[:cfg.n_layers])
     elif cfg.family == "audio":
-        enc = [
-            _init_block(keys[l], cfg, "attn", False, dt) for l in range(cfg.enc_layers)
-        ]
-        dec = []
-        for l in range(cfg.n_layers):
-            blk = _init_block(keys[cfg.enc_layers + l], cfg, "attn", False, dt)
+        def dec_block(k):
+            blk = _init_block(k, cfg, "attn", False, dt)
             blk["norm_x"] = jnp.ones((cfg.d_model,), dt)
-            blk["cross"] = L.init_attention(
-                jax.random.fold_in(keys[cfg.enc_layers + l], 7), cfg, dt
-            )
-            dec.append(blk)
-        p["encoder"] = _stack(enc)
-        p["decoder"] = _stack(dec)
+            blk["cross"] = L.init_attention(jax.random.fold_in(k, 7), cfg, dt)
+            return blk
+
+        p["encoder"] = jax.lax.map(
+            lambda k: _init_block(k, cfg, "attn", False, dt), keys[:cfg.enc_layers])
+        p["decoder"] = jax.lax.map(
+            dec_block, keys[cfg.enc_layers:cfg.enc_layers + cfg.n_layers])
         p["enc_final_norm"] = jnp.ones((cfg.d_model,), dt)
-    elif cfg.family == "hybrid":
-        period = cfg.attn_period
+    elif cfg.family in ("hybrid", "ssm"):
+        # layer g * period + pos is stacked at index g of position pos
+        period = cfg.attn_period if cfg.family == "hybrid" else len(cfg.block_pattern)
         n_periods = cfg.n_layers // period
-        per_pos: list[list[Params]] = [[] for _ in range(period)]
-        for g in range(n_periods):
-            for pos in range(period):
-                l = g * period + pos
-                per_pos[pos].append(
-                    _init_block(keys[l], cfg, cfg.layer_kind(l), cfg.layer_is_moe(l), dt)
-                )
-        p["periods"] = [_stack(blocks) for blocks in per_pos]
-    elif cfg.family == "ssm":
-        period = len(cfg.block_pattern)
-        n_periods = cfg.n_layers // period
-        per_pos = [[] for _ in range(period)]
-        for g in range(n_periods):
-            for pos in range(period):
-                l = g * period + pos
-                per_pos[pos].append(_init_block(keys[l], cfg, cfg.layer_kind(l), False, dt))
-        p["periods"] = [_stack(blocks) for blocks in per_pos]
+        grid = keys[:n_periods * period].reshape(n_periods, period, *keys.shape[1:])
+        p["periods"] = [
+            jax.lax.map(lambda k, pos=pos: _init_block(
+                k, cfg, cfg.layer_kind(pos), cfg.layer_is_moe(pos), dt), grid[:, pos])
+            for pos in range(period)
+        ]
     else:
         raise ValueError(cfg.family)
     return p

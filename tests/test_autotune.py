@@ -276,12 +276,14 @@ class TestSupervisorDecisions:
 
 class TestSupervisedOnlineSearch:
     def test_online_search_task_reports_incumbent_and_candidate(self):
-        prog = logit_pipeline_program(vocab=64, slots=2)
+        # 1024 vocab rows: the sequential incumbent's fori loop is several
+        # times the vectorized chain's time, well outside CPU timing noise
+        prog = logit_pipeline_program(vocab=1024, slots=2)
         db = stale_database(prog)
         fp, _ = nest_coords(prog)
         task = {"name": prog.name, "nest_index": 0, "backend": "xla",
                 "fingerprint": fp, "iterations": 1, "population": 2,
-                "repeats": 1, "deadline_s": 30.0, "program_key": "k",
+                "repeats": 3, "deadline_s": 30.0, "program_key": "k",
                 "incumbent": db.lookup_exact(fp).to_json(), "program": prog}
         results, quarantined = run_supervised(
             [task], jobs=1, verbose=False, worker=online_search_task)
@@ -349,6 +351,27 @@ class TestRegistry:
         sup = SearchSupervisor(stale_database(prog), mode="spawn")
         with pytest.raises(ValueError, match="builder"):
             sup.register(prog)
+
+
+class TestOneProcessPerChip:
+    """On a TPU this process holds the chip, so no spawn pool may start."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+
+    def test_process_pool_refused(self, on_tpu):
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            run_supervised([{"fingerprint": "a"}, {"fingerprint": "b"}],
+                           jobs=2, verbose=False)
+
+    def test_spawn_supervisor_refused(self, on_tpu):
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            SearchSupervisor(TuningDatabase(), mode="spawn")
+
+    def test_in_process_modes_allowed(self, on_tpu):
+        assert run_supervised([], jobs=1, verbose=False) == ([], {})
+        assert SearchSupervisor(TuningDatabase(), mode="sync").mode == "sync"
 
 
 class TestOnlineEndToEnd:

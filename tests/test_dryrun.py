@@ -53,10 +53,11 @@ def test_small_mesh_train_lowering_subprocess():
         from repro.launch.sharding import param_specs, batch_specs
         from repro.launch.dryrun import collective_bytes
         from repro.configs.base import SHAPES
+        from repro.launch.mesh import make_mesh, set_mesh
 
         cfg = get_config('mixtral-8x7b').reduced()
         for shape, axes in [((2,4), ('data','model')), ((2,2,2), ('pod','data','model'))]:
-            mesh = jax.make_mesh(shape, axes)
+            mesh = make_mesh(shape, axes)
             params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
             opt = jax.eval_shape(partial(adamw_init), params)
             pspecs = param_specs(params, mesh)
@@ -67,7 +68,6 @@ def test_small_mesh_train_lowering_subprocess():
             step = make_train_step(cfg, AdamWConfig())
             ws = lambda t, s: jax.tree_util.tree_map(
                 lambda a, b: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=b), t, s)
-            from repro.launch.mesh import set_mesh
             with set_mesh(mesh):
                 lowered = jax.jit(step).lower(ws(params, pspecs), ws(opt, ospecs), ws(batch, bspecs))
                 compiled = lowered.compile()
@@ -94,9 +94,10 @@ def test_decode_small_mesh_subprocess():
         from repro.configs import get_config
         from repro.models import model as M
         from repro.launch.sharding import param_specs, state_specs
+        from repro.launch.mesh import make_mesh, set_mesh
 
         cfg = get_config('h2o-danube-3-4b').reduced()
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
         state = jax.eval_shape(lambda: M.init_decode_state(cfg, 4, 128))
         pspecs = param_specs(params, mesh)
@@ -104,7 +105,6 @@ def test_decode_small_mesh_subprocess():
         ws = lambda t, s: jax.tree_util.tree_map(
             lambda a, b: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=b), t, s)
         tok = jax.ShapeDtypeStruct((4, 1), jnp.int32)
-        from repro.launch.mesh import set_mesh
         with set_mesh(mesh):
             lowered = jax.jit(partial(M.decode_step, cfg)).lower(
                 ws(params, pspecs), ws(state, sspecs), tok)

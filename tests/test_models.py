@@ -38,6 +38,19 @@ def test_forward_shapes_and_finite(arch):
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_init_params_jit_matches_eager(arch):
+    """init_params runs under jit (only the stacked weights materialise);
+    it draws bit for bit what the same code draws eagerly."""
+    cfg = get_config(arch).reduced()
+    got = M.init_params(cfg, jax.random.PRNGKey(0))
+    with jax.disable_jit():
+        want = M.init_params(cfg, jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and np.array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_train_step_reduces_loss_and_stays_finite(arch):
     cfg = get_config(arch).reduced()
     params = M.init_params(cfg, jax.random.PRNGKey(0))

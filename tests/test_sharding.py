@@ -60,7 +60,13 @@ class TestMeshHelpers:
     def test_set_mesh_context_manager(self):
         mesh = make_mesh((jax.device_count(),), ("data",))
         with set_mesh(mesh):
-            pass  # both the jax.set_mesh and the Mesh-as-context path
+            assert jax.sharding.get_abstract_mesh().axis_names == ("data",)
+
+    def test_make_mesh_axes_are_auto(self):
+        from jax.sharding import AxisType
+
+        mesh = make_mesh((1, jax.device_count()), ("data", "model"))
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
 
 # ---------------------------------------------------------------------------

@@ -88,6 +88,41 @@ class TestPlanner:
         # auto-chosen tiles stay VPU-aligned (sublane 8 / lane 128 multiples)
         assert tiles[-1] % 128 == 0 and tiles[-2] % 8 == 0
 
+    def test_vmem_estimate_pads_blocks_to_tpu_tiles(self):
+        # a constant subscript keeps its dimension as extent 1, which the TPU
+        # pads to 128 lanes; every pipelined block is double-buffered
+        comp = Computation("cp", acc("B", "i", "j"), (acc("A", "i", "j", aff(const=0)),),
+                           lambda v: v * 2.0)
+        prog = Program("lanes", (Array("A", (64, 256, 3)), Array("B", (64, 256))),
+                       (Loop("i", 64, body=(Loop("j", 256, body=(comp,)),)),))
+        plan = plan_nest_tiling(prog, prog.body[0], tile=(64, 256))
+        a_block = 64 * 256 * 128 * 4          # (64, 256, 1) -> (64, 256, 128)
+        b_block = 64 * 256 * 4
+        assert plan.vmem_bytes == 2 * a_block + 2 * 2 * b_block
+        # under a budget the planner shrinks the tiles to fit
+        small = plan_nest_tiling(prog, prog.body[0], vmem_budget=1 << 22)
+        assert small.vmem_bytes <= 1 << 22
+        assert [a.tile for a in small.parallel] != [64, 256]
+
+    def test_reduction_slab_counts_toward_vmem(self):
+        prog = normalize(BENCHMARKS["gemm"].make("b", "bench"))
+        plan = plan_nest_tiling(prog, prog.body[-1])
+        assert plan.kind == "reduce" and plan.vmem_bytes <= 1 << 23
+        slab = np.prod([a.tile for a in plan.axes]) * 4
+        assert slab < 1 << 23  # the (i, j, k) product no longer spans 320^3
+
+    def test_rank1_tiles_align_to_whole_mosaic_tiles(self):
+        # Mosaic tiles a rank-1 operand in 8 x 128 = 1024 elements: an auto
+        # tile of a rank-1 array is a multiple of 1024 or the whole extent
+        n = 3000
+        comp = Computation("ax", acc("y", "j"), (acc("A", "i", "j"), acc("x", "i")),
+                           lambda a, b: a * b, accumulate="+")
+        prog = Program("atax", (Array("A", (n, n)), Array("x", (n,)), Array("y", (n,))),
+                       (Loop("j", n, body=(Loop("i", n, body=(comp,)),)),))
+        plan = plan_nest_tiling(prog, prog.body[0], vmem_budget=1 << 22)
+        for a in plan.axes:
+            assert a.tile == n or a.tile % 1024 == 0, (a.name, a.tile)
+
     def test_halo_covers_stencil_offsets(self):
         n = 10
         st = Computation(
@@ -219,6 +254,41 @@ def test_guarded_reduction_with_unroll(unroll):
     out = run_f32(prog, sched, inp)
     assert nest_kernel.EMITTED["pallas_reduce"] == before + 1
     assert max_rel(out["C"], ref["C"]) < 1e-5
+
+
+def _ref_reads(jaxpr, refs) -> int:
+    """How many ``get``s of ``refs`` a kernel jaxpr makes, into ``pl.when``
+    branches."""
+    n = 0
+    for e in jaxpr.eqns:
+        if e.primitive.name == "get" and any(e.invars[0] is r for r in refs):
+            n += 1
+        if e.primitive.name == "cond":
+            for br in e.params["branches"]:
+                inner = [v for v, a in zip(br.jaxpr.invars, e.invars[1:])
+                         if any(a is r for r in refs)]
+                n += _ref_reads(br.jaxpr, inner)
+    return n
+
+
+@pytest.mark.parametrize("tile", [(8, 128, 8), None])
+def test_nest_kernels_never_read_their_output_blocks(tile):
+    """On a TPU an output block is never loaded from HBM, so a kernel that
+    reads it sees what an earlier block left in VMEM (interpret mode hides
+    this: it starts outputs from the aliased inputs).  Old content must
+    come from the aliased input block."""
+    import jax
+
+    prog = normalize(BENCHMARKS["correlation"].make("a", "mini"))
+    fn = compile_jax(prog, Schedule(mode="canonical", use_idioms=False, pallas_nest=True,
+                                    pallas_reduce=True, nest_tile=tile))
+    jaxpr = jax.make_jaxpr(fn)(random_inputs(prog, seed=0))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert any(e.params["jaxpr"].eqns and len(e.outvars) for e in calls)
+    for e in calls:
+        kernel = e.params["jaxpr"]
+        outs = kernel.invars[len(e.invars):len(e.invars) + len(e.outvars)]
+        assert _ref_reads(kernel, outs) == 0
 
 
 def test_unroll_flows_from_recipe_to_schedule():

@@ -188,6 +188,7 @@ def test_elastic_remesh_subprocess():
         from repro.models import model as M
         from repro.launch.sharding import param_specs
         from repro.train.checkpoint import CheckpointManager
+        from repro.launch.mesh import make_mesh, set_mesh
 
         cfg = get_config('minicpm-2b').reduced()
         params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -197,12 +198,11 @@ def test_elastic_remesh_subprocess():
 
         for shape, axes in [((2, 2), ('data','model')), ((2, 4), ('data','model')),
                             ((2, 2, 2), ('pod','data','model'))]:
-            mesh = jax.make_mesh(shape, axes, devices=jax.devices()[:int(np.prod(shape))])
+            mesh = make_mesh(shape, axes, devices=jax.devices()[:int(np.prod(shape))])
             specs = param_specs(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))), mesh)
             _, restored, _ = mgr.restore(params)
             placed = jax.tree_util.tree_map(jax.device_put, restored, specs)
             batch = {'tokens': jnp.zeros((4, 8), jnp.int32)}
-            from repro.launch.mesh import set_mesh
             with set_mesh(mesh):
                 logits = jax.jit(lambda p, b: M.forward(cfg, p, b))(placed, batch)
             assert logits.shape == (4, 8, cfg.vocab)
