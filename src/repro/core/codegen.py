@@ -26,12 +26,23 @@ On top of the canonical path, two recipe-selected lowerings:
 Legality is decided with the same dependence machinery the normalizer uses:
 an iterator may be materialized as an array axis iff no dependence of the
 nest is carried by it (reduction self-deps of flagged accumulations exempt).
+
+Names on the device: the function ``compile_jax`` builds is named after the
+program (``daisy_<program>``, so its XLA module is ``jit_daisy_<program>``);
+top-level nest ``i`` is emitted under ``jax.named_scope(f"nest{i}")`` and
+each lowering the emitter picks under a scope of its own name (``einsum``,
+``vectorize``, ``scan``, ``fori``, ``pallas_nest``, ``pallas_reduce``,
+``pallas_gemm``), so a device op's ``op_name`` reads like
+``jit(daisy_heat_3d)/nest0/fori/...``.  Emission is recorded as a
+``codegen.emit`` span with one ``codegen.nest`` span per top-level nest
+(``repro.core.spans``); it runs only while JAX traces.
 """
 from __future__ import annotations
 
 import itertools
 import math
 import weakref
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from dataclasses import replace as dc_replace
 from typing import Any, Callable, Mapping, Sequence
@@ -56,6 +67,7 @@ from .ir import (
     nest_computations,
     walk,
 )
+from .spans import module_name, span
 
 # Shared accumulate-op semantics: neutral elements and reducers.  The Pallas
 # nest kernel (repro.kernels.nest_kernel) imports these (plus ``_combine``)
@@ -274,6 +286,22 @@ class _NestEmitter:
     def __init__(self, program: Program, schedule: Schedule):
         self.p = program
         self.s = schedule
+        self.lowerings: list[str] = []  # outermost lowerings emitted, in order
+        self._depth = 0
+
+    @contextmanager
+    def _lowering(self, kind: str):
+        """Emit the enclosed ops under ``jax.named_scope(kind)``; an outermost
+        lowering that completes is added to ``self.lowerings``."""
+        outer = self._depth == 0
+        self._depth += 1
+        try:
+            with jax.named_scope(kind):
+                yield
+        finally:
+            self._depth -= 1
+        if outer and kind not in self.lowerings:
+            self.lowerings.append(kind)
 
     # -- planning -----------------------------------------------------------
     def plan(self, nest: Node) -> dict[str, bool]:
@@ -338,7 +366,7 @@ class _NestEmitter:
             try:
                 from ..kernels.nest_kernel import emit_nest
 
-                return emit_nest(self.p, nest, env, self.s)
+                return emit_nest(self.p, nest, env, self.s, lowering=self._lowering)
             except Unsupported:
                 pass  # outside the tiled class: generic lowering below
         self.vec_plan = self.plan(nest)
@@ -381,7 +409,8 @@ class _NestEmitter:
                 e = self._emit(child, e, s2, vec_axes)
             return tuple(e[a] for a in carried)
 
-        out = lax.fori_loop(0, node.trip_count, body, tuple(env[a] for a in carried))
+        with self._lowering("fori"):
+            out = lax.fori_loop(0, node.trip_count, body, tuple(env[a] for a in carried))
         env = dict(env)
         env.update(dict(zip(carried, out)))
         return env
@@ -432,11 +461,15 @@ class _NestEmitter:
         if cls is None:
             return None
         written_lb, readonly = cls
-        t, start, n = node.iterator, node.start, node.trip_count
         for name in list(written_lb) + sorted(readonly):
             arr = env[name]
-            if arr.ndim == 0 or node.start + n > arr.shape[0]:
+            if arr.ndim == 0 or node.start + node.trip_count > arr.shape[0]:
                 return None  # leading axis does not cover the loop range
+        with self._lowering("scan"):
+            return self._scan_loop(node, env, seq_env, vec_axes, written_lb, readonly)
+
+    def _scan_loop(self, node: Loop, env, seq_env, vec_axes, written_lb, readonly):
+        t, start, n = node.iterator, node.start, node.trip_count
         sliceable = set(written_lb) | readonly
 
         def lag_name(a: str, d: int) -> str:
@@ -585,6 +618,12 @@ class _NestEmitter:
                 env = dict(env)
                 env[comp.write.array] = out
                 return env
+        with self._lowering("vectorize"):
+            return self._emit_vector(comp, env, seq_env, axes)
+
+    def _emit_vector(self, comp, env, seq_env, axes):
+        """The generic lowering of one computation over its vector axes:
+        gather the reads, evaluate, mask, reduce and write back."""
         vals = comp.expr(*[self._gather(r, env, axes, seq_env) for r in comp.reads])
         full_shape = tuple(a.trip for a in axes)
         vals = jnp.broadcast_to(vals, jnp.broadcast_shapes(jnp.shape(vals), full_shape))
@@ -745,25 +784,27 @@ class _NestEmitter:
         arr = env[comp.write.array]
         if tuple(ax_of[l].trip for l in w[0]) != arr.shape:
             return None  # partial-cover writes take the generic path
-        contrib = None
         if self.s.pallas_gemm and len(operands) == 2:
             # canonical 2-operand contraction -> Pallas MXU kernel; a
             # contraction the GEMM cannot express takes jnp.einsum
             from ..kernels import ops as kops
 
             try:
-                contrib = kops.einsum2(
-                    subs[0], subs[1], out_sub, operands[0], operands[1],
-                    tile=self.s.tile, interpret=self.s.interpret_kernels,
-                )
+                with self._lowering("pallas_gemm"):
+                    return _scaled_add(arr, c, kops.einsum2(
+                        subs[0], subs[1], out_sub, operands[0], operands[1],
+                        tile=self.s.tile, interpret=self.s.interpret_kernels,
+                    ))
             except kops.NotAContraction:
-                contrib = None
-        if contrib is None:
-            spec = ",".join(subs) + "->" + out_sub
-            contrib = jnp.einsum(spec, *operands)
-        if c != 1.0:
-            contrib = contrib * c
-        return arr + contrib.astype(arr.dtype)
+                pass
+        with self._lowering("einsum"):
+            return _scaled_add(arr, c, jnp.einsum(",".join(subs) + "->" + out_sub, *operands))
+
+
+def _scaled_add(arr, c: float, contrib):
+    if c != 1.0:
+        contrib = contrib * c
+    return arr + contrib.astype(arr.dtype)
 
 
 def _combine(acc: str, a, b):
@@ -796,20 +837,32 @@ def compile_jax(
 
     def fn(inputs: Mapping[str, Any]) -> dict[str, Any]:
         """Run every nest under its schedule; returns the array environment."""
-        env = {
-            a.name: (
-                jnp.zeros(a.shape, dtype=jnp.float32)
-                if a.name in program.temps
-                else jnp.asarray(inputs[a.name])
-            )
-            for a in program.arrays
-        }
-        for nest, sched in zip(program.body, schedules):
-            em = _NestEmitter(program, sched)
-            env = em.emit(nest, env)
+        with span("codegen.emit", program=program.name):
+            env = {
+                a.name: (
+                    jnp.zeros(a.shape, dtype=jnp.float32)
+                    if a.name in program.temps
+                    else jnp.asarray(inputs[a.name])
+                )
+                for a in program.arrays
+            }
+            for i, (nest, sched) in enumerate(zip(program.body, schedules)):
+                env = _emit_top_nest(program, i, nest, sched, env)
         return env
 
+    fn.__name__ = fn.__qualname__ = module_name(program.name)
     return fn
+
+
+def _emit_top_nest(program: Program, index: int, nest: Node, schedule: Schedule,
+                   env: dict[str, Any]) -> dict[str, Any]:
+    """Emit top-level nest ``index`` under ``jax.named_scope(f"nest{index}")``,
+    recorded as a ``codegen.nest`` span with the lowering(s) it took."""
+    with span("codegen.nest", index=index) as s, jax.named_scope(f"nest{index}"):
+        em = _NestEmitter(program, schedule)
+        env = em.emit(nest, env)
+        s.attrs["lowering"] = "+".join(em.lowerings)
+    return env
 
 
 def run_jax(

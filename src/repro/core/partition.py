@@ -27,10 +27,12 @@ every canonical nest.  This module picks it up and maps it onto a mesh axis:
 * ``compile_sharded`` — the executor.  Builds the shard-local program (loop
   extents and array dims divided by the mesh axis, padded up when the extent
   does not divide), emits each nest through the existing per-nest lowering
-  (``_NestEmitter``: einsum idioms, Pallas kernels, scan recurrences — all
-  unchanged inside the shard), inserts the all-reduce (``psum``/``pmax``/
-  ``pmin``) after nests that accumulate over their sharded iterator, and
-  wraps the whole body in ``shard_map`` with one ``PartitionSpec`` per array.
+  (``_NestEmitter`` under its ``nest<i>`` scope: einsum idioms, Pallas
+  kernels, scan recurrences — all unchanged inside the shard), inserts the
+  all-reduce (``psum``/``pmax``/``pmin``) after nests that accumulate over
+  their sharded iterator, and wraps the whole body in ``shard_map`` with one
+  ``PartitionSpec`` per array.  The function is named after the program, as
+  ``compile_jax``'s is.
   When nothing shards (or the mesh axis is 1) it returns the plain
   single-device lowering — sharding is always a sound no-op to request.
 """
@@ -44,7 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from .codegen import Schedule, _NestEmitter, compile_jax
+from .codegen import Schedule, _emit_top_nest, compile_jax
 from .dependence import EQ, nest_direction_vectors
 from .ir import (
     Array,
@@ -56,6 +58,7 @@ from .ir import (
     nest_computations,
     walk,
 )
+from .spans import module_name, span
 
 # accumulate ops with a mesh collective (no pprod exists; '*' stays vetoed)
 _SHARD_REDUCE = {"+", "max", "min"}
@@ -385,16 +388,17 @@ def compile_sharded(
 
     def local_fn(*vals):
         """Per-shard body: run every nest locally, all-reducing as planned."""
-        env: dict[str, jnp.ndarray] = {}
-        lvals = dict(zip(in_names, vals))
-        for a in local.arrays:
-            env[a.name] = (jnp.zeros(a.shape, jnp.float32)
-                           if a.name in local.temps else lvals[a.name])
-        for nest, sched, np_ in zip(local.body, schedules, plan.nests):
-            old = {arr: env[arr] for arr, _ in np_.reduces}
-            env = _NestEmitter(local, sched).emit(nest, env)
-            for arr, op in np_.reduces:
-                env[arr] = _all_reduce(op, old[arr], env[arr], axis)
+        with span("codegen.emit", program=program.name):
+            env: dict[str, jnp.ndarray] = {}
+            lvals = dict(zip(in_names, vals))
+            for a in local.arrays:
+                env[a.name] = (jnp.zeros(a.shape, jnp.float32)
+                               if a.name in local.temps else lvals[a.name])
+            for i, (nest, sched, np_) in enumerate(zip(local.body, schedules, plan.nests)):
+                old = {arr: env[arr] for arr, _ in np_.reduces}
+                env = _emit_top_nest(local, i, nest, sched, env)
+                for arr, op in np_.reduces:
+                    env[arr] = _all_reduce(op, old[arr], env[arr], axis)
         return tuple(env[k] for k in all_names)
 
     # replicated out-specs (redundantly computed arrays, all-reduced
@@ -429,6 +433,7 @@ def compile_sharded(
                      for i, s in enumerate(v.shape)])
         return outs
 
+    fn.__name__ = fn.__qualname__ = module_name(program.name)
     return fn, plan
 
 

@@ -20,14 +20,18 @@ instead of living inside a hardcoded function chain:
                      ``CompilationCache`` keyed by the *input* program's
                      content fingerprint (so two programs sharing a prefix
                      of identical intermediate forms share the work).
+
+Every pipeline run is recorded as a ``daisy.pipeline`` span with one
+``pass:<name>`` span per pass (``repro.core.spans``), with or without a
+``PassContext``; the pass span's timer is the one ``PassContext`` records.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator, Protocol, Sequence, runtime_checkable
 
 from .ir import Program, program_computations, program_fingerprint
+from .spans import span
 
 
 @runtime_checkable
@@ -92,6 +96,16 @@ class PassRecord:
     after: Program | None = None
 
 
+def ir_sizes(before: Program, after: Program) -> dict[str, int]:
+    """Top-level nests and computations before and after a pass."""
+    return {
+        "nests_before": len(before.body),
+        "nests_after": len(after.body),
+        "comps_before": len(program_computations(before)),
+        "comps_after": len(program_computations(after)),
+    }
+
+
 class PassContext:
     """Carries observability across one pipeline run.
 
@@ -119,15 +133,14 @@ class PassContext:
         before: Program,
         after: Program,
         cached: bool = False,
+        sizes: dict[str, int] | None = None,
     ) -> PassRecord:
-        """Finalize one pass run into a ``PassRecord`` (folds pending stats)."""
+        """Finalize one pass run into a ``PassRecord`` (folds pending stats);
+        ``sizes`` is ``ir_sizes(before, after)`` when already counted."""
         rec = PassRecord(
             name=name,
             seconds=seconds,
-            nests_before=len(before.body),
-            nests_after=len(after.body),
-            comps_before=len(program_computations(before)),
-            comps_after=len(program_computations(after)),
+            **(sizes or ir_sizes(before, after)),
             stats=self._pending.pop(name, {}),
             cached=cached,
             before=before if self.snapshots else None,
@@ -251,22 +264,25 @@ class PassPipeline:
         """Run every pass in order, recording into ``ctx`` and memoizing
         per-pass results in ``cache`` when one is given."""
         cur = program
-        for p in self._passes:
-            t0 = time.perf_counter()
-            cached = False
-            if cache is not None:
-                key = ("pass", p.name, program_fingerprint(cur))
-                hit = cache.get(key)
-                if hit is not None:
-                    nxt, cached = hit, True
-                else:
-                    nxt = p.run(cur, ctx)
-                    cache.put(key, nxt)
-            else:
-                nxt = p.run(cur, ctx)
-            if ctx is not None:
-                ctx.record(p.name, time.perf_counter() - t0, cur, nxt, cached=cached)
-            cur = nxt
+        with span("daisy.pipeline", pipeline=self.name, program=program.name):
+            for p in self._passes:
+                cached = False
+                with span(f"pass:{p.name}") as s:
+                    if cache is not None:
+                        key = ("pass", p.name, program_fingerprint(cur))
+                        hit = cache.get(key)
+                        if hit is not None:
+                            nxt, cached = hit, True
+                        else:
+                            nxt = p.run(cur, ctx)
+                            cache.put(key, nxt)
+                    else:
+                        nxt = p.run(cur, ctx)
+                sizes = ir_sizes(cur, nxt)
+                s.attrs.update(sizes, cached=cached)
+                if ctx is not None:
+                    ctx.record(p.name, s.seconds, cur, nxt, cached=cached, sizes=sizes)
+                cur = nxt
         return cur
 
     def run_with_report(self, program: Program, snapshots: bool = False) -> tuple[Program, PassContext]:

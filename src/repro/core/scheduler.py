@@ -52,6 +52,7 @@ from .search import (
     nest_rng_seed,
     schedule_from_recipe,
 )
+from .spans import span
 
 
 @dataclass
@@ -250,15 +251,18 @@ class Daisy:
             return cached
         p = self._normalized(program, fp) if normalize_first else program
         plans: list[NestPlan] = []
-        for nest in p.body:
-            nest_fp = fingerprint(nest)
-            emb = embed_nest(p, nest)
-            idiom = classify_nest(nest)
-            recipe, source = self.db.lookup(nest_fp, emb)
-            if recipe is None:
-                recipe = default_recipe_for(idiom)
-                source = f"default({idiom.kind})"
-            plans.append(NestPlan(nest_fp, idiom.kind, recipe, source))
+        with span("daisy.plan", program=program.name) as s:
+            for nest in p.body:
+                nest_fp = fingerprint(nest)
+                emb = embed_nest(p, nest)
+                idiom = classify_nest(nest)
+                recipe, source = self.db.lookup(nest_fp, emb)
+                if recipe is None:
+                    recipe = default_recipe_for(idiom)
+                    source = f"default({idiom.kind})"
+                plans.append(NestPlan(nest_fp, idiom.kind, recipe, source))
+            s.attrs.update(nests=len(plans),
+                           from_db=sum(not n.source.startswith("default") for n in plans))
         result = ProgramPlan(p, plans)
         self.cache.put(key, result)
         return result
@@ -267,29 +271,35 @@ class Daisy:
     def compile(
         self, program: Program, normalize_first: bool = True, jit: bool = True
     ) -> tuple[Callable[[Mapping[str, np.ndarray]], dict], ProgramPlan]:
-        """Plan and lower ``program``; returns (callable, plan), memoized."""
-        fp = program_fingerprint(program)
-        key = ("compile", jit) + self._plan_key(fp, normalize_first)
-        cached = self.cache.get(key)
-        if cached is not None:
-            return cached
-        plan = self.plan(program, normalize_first=normalize_first, _fp=fp)
-        per_nest = [
-            schedule_from_recipe(
-                self._backend_recipe(np_.recipe), self.interpret,
-                shard_axis=self.shard_axis if self.mesh is not None else None)
-            for np_ in plan.nests
-        ]
-        if self.mesh is not None:
-            from .partition import compile_sharded
+        """Plan and lower ``program``; returns (callable, plan), memoized.
 
-            fn, plan.partition = compile_sharded(
-                plan.program, per_nest, mesh=self.mesh, axis=self.shard_axis)
-        else:
-            fn = compile_jax(plan.program, per_nest)
-        result = ((jax.jit(fn) if jit else fn), plan)
-        self.cache.put(key, result)
-        return result
+        Recorded as a ``daisy.compile`` span; a memo hit is marked ``cached``.
+        The returned function traces (``codegen.emit``) and compiles
+        (``jax.lower``, ``xla.compile``) on its first call."""
+        with span("daisy.compile", program=program.name) as s:
+            fp = program_fingerprint(program)
+            key = ("compile", jit) + self._plan_key(fp, normalize_first)
+            cached = self.cache.get(key)
+            s.attrs["cached"] = cached is not None
+            if cached is not None:
+                return cached
+            plan = self.plan(program, normalize_first=normalize_first, _fp=fp)
+            per_nest = [
+                schedule_from_recipe(
+                    self._backend_recipe(np_.recipe), self.interpret,
+                    shard_axis=self.shard_axis if self.mesh is not None else None)
+                for np_ in plan.nests
+            ]
+            if self.mesh is not None:
+                from .partition import compile_sharded
+
+                fn, plan.partition = compile_sharded(
+                    plan.program, per_nest, mesh=self.mesh, axis=self.shard_axis)
+            else:
+                fn = compile_jax(plan.program, per_nest)
+            result = ((jax.jit(fn) if jit else fn), plan)
+            self.cache.put(key, result)
+            return result
 
     # -- seeding (paper: A variants define the database) -----------------------
     def _prepare_nest(self, p: Program, nest: Node, source: str) -> "_SeedItem":

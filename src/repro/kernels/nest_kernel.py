@@ -50,9 +50,12 @@ def emit_nest(
     nest: Node,
     env: dict[str, Any],
     schedule,
+    lowering=jax.named_scope,
 ) -> dict[str, Any]:
     """Lower one canonical nest via ``pl.pallas_call``; raises ``TilingError``
-    (an ``Unsupported``) when the nest is outside the tiled class."""
+    (an ``Unsupported``) when the nest is outside the tiled class.  The
+    kernel is emitted under ``lowering(kind)``, ``kind`` being
+    ``pallas_nest`` or ``pallas_reduce``."""
     plan = plan_nest_tiling(
         program, nest, tile=schedule.nest_tile, vmem_budget=schedule.vmem_budget
     )
@@ -61,11 +64,12 @@ def emit_nest(
     if plan.kind == "parallel" and not schedule.pallas_nest:
         raise TilingError("parallel nest but pallas_nest disabled")
 
-    emitter = _KernelBuilder(program, plan, env,
-                             unroll=max(1, int(schedule.unroll)),
-                             interpret=schedule.interpret_kernels)
-    out_env = emitter.build()
-    EMITTED["pallas_nest" if plan.kind == "parallel" else "pallas_reduce"] += 1
+    kind = "pallas_nest" if plan.kind == "parallel" else "pallas_reduce"
+    with lowering(kind):
+        out_env = _KernelBuilder(program, plan, env,
+                                 unroll=max(1, int(schedule.unroll)),
+                                 interpret=schedule.interpret_kernels).build()
+    EMITTED[kind] += 1
     return out_env
 
 
