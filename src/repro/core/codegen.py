@@ -26,6 +26,10 @@ On top of the canonical path, two recipe-selected lowerings:
 Legality is decided with the same dependence machinery the normalizer uses:
 an iterator may be materialized as an array axis iff no dependence of the
 nest is carried by it (reduction self-deps of flagged accumulations exempt).
+In canonical mode the plan also drops access pairs that the loop bounds and
+guards prove disjoint (``dependence.guarded_disjoint``), so correlation's
+triangular ``corr[k6,k5] = corr[k5,k6] if k6 > k5`` becomes one masked
+transpose rather than a loop over single elements.
 
 Names on the device: the function ``compile_jax`` builds is named after the
 program (``daisy_<program>``, so its XLA module is ``jit_daisy_<program>``);
@@ -64,7 +68,6 @@ from .ir import (
     Node,
     Program,
     loop_iterators,
-    nest_computations,
     walk,
 )
 from .spans import module_name, span
@@ -184,8 +187,9 @@ class Schedule:
         return pallas_backend() != "pallas"
 
 
-# Trace-time lowering counters (tests assert which path actually fired).
-LOWERING_STATS = {"scan": 0, "fori": 0}
+# Trace-time lowering counters (tests assert which path actually fired);
+# ``guard_disjoint`` counts loop iterators only the guard test vectorized.
+LOWERING_STATS = {"scan": 0, "fori": 0, "guard_disjoint": 0}
 
 
 @dataclass
@@ -287,6 +291,7 @@ class _NestEmitter:
         self.p = program
         self.s = schedule
         self.lowerings: list[str] = []  # outermost lowerings emitted, in order
+        self.guard_disjoint = 0  # iterators the guard test made vectorizable
         self._depth = 0
 
     @contextmanager
@@ -312,22 +317,39 @@ class _NestEmitter:
         computations it encloses is carried by its iterator.  Dependences
         between sibling nests are enforced by their sequential emission
         order and do not constrain vectorization.
+
+        Guards enter legality in canonical mode only: a pair of accesses that
+        the loop bounds and guards prove disjoint (``guarded_disjoint``)
+        carries no dependence; ``self.guard_disjoint`` counts the iterators
+        vectorized thanks to that test alone.
         """
+        self.guard_disjoint = 0
         if isinstance(nest, Computation):
             return {}
         iterators = list(loop_iterators(nest))
         legal: dict[str, bool] = {}
+        by_guards: set[str] = set()
 
-        def visit(n: Node) -> None:
+        def carried(vecs) -> bool:
+            return any(v.directions[0] != EQ for v in vecs)
+
+        def visit(n: Node, outer: tuple[Loop, ...]) -> None:
             if isinstance(n, Computation):
                 return
-            comps = nest_computations(n)
-            vecs = nest_direction_vectors([n.iterator], {n.iterator: n.trip_count}, comps)
-            legal[n.iterator] = all(v.directions[0] == EQ for v in vecs)
+            placed = list(walk(n, outer))
+            comps = [c for _, c in placed]
+            trip = {n.iterator: n.trip_count}
+            legal[n.iterator] = not carried(
+                nest_direction_vectors([n.iterator], trip, comps))
+            if not legal[n.iterator] and self.s.mode == "canonical":
+                legal[n.iterator] = not carried(nest_direction_vectors(
+                    [n.iterator], trip, comps, loops=[l for l, _ in placed]))
+                if legal[n.iterator]:
+                    by_guards.add(n.iterator)
             for b in n.body:
-                visit(b)
+                visit(b, outer + (n,))
 
-        visit(nest)
+        visit(nest, ())
         if self.s.mode == "as_written":
             # only each computation's innermost enclosing loop is vectorized
             inner: set[str] = set()
@@ -346,6 +368,7 @@ class _NestEmitter:
                     break
                 vec[l.iterator] = False
                 prod //= max(1, l.trip_count)
+        self.guard_disjoint = sum(vec[it] for it in by_guards)
         return vec
 
     def _trips(self, nest: Node) -> dict[str, int]:
@@ -370,6 +393,7 @@ class _NestEmitter:
             except Unsupported:
                 pass  # outside the tiled class: generic lowering below
         self.vec_plan = self.plan(nest)
+        LOWERING_STATS["guard_disjoint"] += self.guard_disjoint
         return self._emit(nest, env, {}, [])
 
     def _emit(
@@ -857,11 +881,13 @@ def compile_jax(
 def _emit_top_nest(program: Program, index: int, nest: Node, schedule: Schedule,
                    env: dict[str, Any]) -> dict[str, Any]:
     """Emit top-level nest ``index`` under ``jax.named_scope(f"nest{index}")``,
-    recorded as a ``codegen.nest`` span with the lowering(s) it took."""
+    recorded as a ``codegen.nest`` span with the lowering(s) it took and the
+    number of iterators the guard test vectorized (``guard_disjoint``)."""
     with span("codegen.nest", index=index) as s, jax.named_scope(f"nest{index}"):
         em = _NestEmitter(program, schedule)
         env = em.emit(nest, env)
         s.attrs["lowering"] = "+".join(em.lowerings)
+        s.attrs["guard_disjoint"] = em.guard_disjoint
     return env
 
 

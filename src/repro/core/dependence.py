@@ -9,6 +9,12 @@ Provides the two legality oracles the normalization passes need (paper §2):
   by stride minimization (a permutation is legal iff every dependence's
   permuted direction vector stays lexicographically non-negative).
 
+``guarded_disjoint`` is a third, stronger test the code generator's
+vectorization plan uses: Fourier–Motzkin elimination over the loop bounds
+and guards proves two accesses never touch the same element (correlation's
+``corr[k6,k5] = corr[k5,k6] if k6 > k5`` writes one triangle and reads the
+other).
+
 Directions are represented per iterator as one of ``'=' '<' '>' '*'`` where
 ``'<'`` means the dependence flows from an earlier to a later iteration
 (positive distance).  Anything we cannot solve exactly becomes ``'*'``
@@ -19,6 +25,7 @@ rewrites are permitted, as in the paper's GEMM interchange).
 from __future__ import annotations
 
 import itertools
+import math
 from dataclasses import dataclass
 from typing import Iterable, Sequence
 
@@ -141,6 +148,117 @@ def _solve_directions(
         else:
             out[it] = GT
     return out
+
+
+# ---------------------------------------------------------------------------
+# Guard-aware disjointness: Fourier–Motzkin over bounds, guards and subscripts
+# ---------------------------------------------------------------------------
+FM_MAX_CONSTRAINTS = 512  # give up (undecided) beyond this many inequalities
+
+# An inequality ``sum(coeffs[v] * v) + const >= 0`` over integer variables.
+_Ineq = tuple[dict[str, int], int]
+
+
+def _side_constraints(
+    tag: str, loops: Sequence[Loop], comp: Computation
+) -> list[_Ineq] | None:
+    """The iteration domain of ``comp`` under ``loops`` as inequalities over
+    the variables ``(tag, iterator)``: ``start <= it <= stop - 1`` per loop
+    (a superset of a strided loop's points) plus ``guard >= 0``.  None when a
+    guard is not affine."""
+    out: list[_Ineq] = []
+    for l in loops:
+        v = f"{tag}.{l.iterator}"
+        out.append(({v: 1}, -l.start))
+        out.append(({v: -1}, l.stop - 1))
+    for g in comp.guards:
+        if not g.is_affine:
+            return None
+        out.append(({f"{tag}.{it}": c for it, c in g.coeffs}, g.const))
+    return out
+
+
+def _fm_infeasible(ineqs: list[_Ineq]) -> bool | None:
+    """Fourier–Motzkin elimination over the rationals: True when the system
+    has no rational (hence no integer) solution, False when it has one,
+    None when it outgrows ``FM_MAX_CONSTRAINTS``."""
+    rows: dict[tuple[tuple[str, int], ...], int] = {}
+
+    def add(coeffs: dict[str, int], const: int) -> None:
+        """Add one row, scaled by its gcd; of equal left sides the tightest
+        constant is kept."""
+        cs = {v: c for v, c in coeffs.items() if c}
+        g = math.gcd(*cs.values(), const)
+        if g > 1:
+            cs = {v: c // g for v, c in cs.items()}
+            const //= g
+        key = tuple(sorted(cs.items()))
+        rows[key] = min(const, rows.get(key, const))
+
+    for cs, c0 in ineqs:
+        add(cs, c0)
+    while True:
+        if any(not key and c0 < 0 for key, c0 in rows.items()):
+            return True
+        live = sorted({v for key in rows for v, _ in key})
+        if not live:
+            return False
+        table = [(dict(key), c0) for key, c0 in rows.items()]
+        # eliminate the variable whose pos x neg pairing adds the fewest rows
+        sides = {v: ([r for r in table if r[0].get(v, 0) > 0],
+                     [r for r in table if r[0].get(v, 0) < 0]) for v in live}
+        v = min(live, key=lambda u: len(sides[u][0]) * len(sides[u][1])
+                - len(sides[u][0]) - len(sides[u][1]))
+        pos, neg = sides[v]
+        rows = {}
+        for cs, c0 in table:
+            if v not in cs:
+                add(cs, c0)
+        for pc, p0 in pos:
+            for nc, n0 in neg:
+                a, b = -nc[v], pc[v]  # a*p + b*n cancels v
+                add({u: a * pc.get(u, 0) + b * nc.get(u, 0)
+                     for u in set(pc) | set(nc) if u != v}, a * p0 + b * n0)
+        if len(rows) > FM_MAX_CONSTRAINTS:
+            return None
+
+
+def guarded_disjoint(
+    loops_a: Sequence[Loop], comp_a: Computation, acc_a: Access,
+    loops_b: Sequence[Loop], comp_b: Computation, acc_b: Access,
+) -> bool:
+    """True only when no iteration point of ``comp_a`` under ``loops_a`` and
+    none of ``comp_b`` under ``loops_b`` make ``acc_a`` and ``acc_b`` address
+    the same element.
+
+    Each side's domain is its loops' bounds plus its guards, over its own
+    copy of the variables; one equality per subscript dimension joins them,
+    and Fourier–Motzkin elimination decides the system.  An infeasible
+    rational system has no integer point, so True is sound.  Anything the
+    test cannot decide (a non-affine subscript or guard, ranks that differ,
+    a system past ``FM_MAX_CONSTRAINTS``) returns False.
+    """
+    if acc_a.array != acc_b.array:
+        return True
+    if len(acc_a.index) != len(acc_b.index):
+        return False
+    if not (acc_a.is_affine and acc_b.is_affine):
+        return False
+    dom_a = _side_constraints("a", loops_a, comp_a)
+    dom_b = _side_constraints("b", loops_b, comp_b)
+    if dom_a is None or dom_b is None:
+        return False
+    ineqs = dom_a + dom_b
+    for ia, ib in zip(acc_a.index, acc_b.index):
+        diff: dict[str, int] = {}
+        for it, c in ia.coeffs:
+            diff[f"a.{it}"] = diff.get(f"a.{it}", 0) + c
+        for it, c in ib.coeffs:
+            diff[f"b.{it}"] = diff.get(f"b.{it}", 0) - c
+        c0 = ia.const - ib.const
+        ineqs.append((diff, c0))
+        ineqs.append(({v: -c for v, c in diff.items()}, -c0))
+    return _fm_infeasible(ineqs) is True
 
 
 def _is_reduction_self_dep(c1: Computation, c2: Computation, a: Access, b: Access) -> bool:
@@ -313,11 +431,17 @@ def nest_direction_vectors(
     iterators: Sequence[str],
     trip: dict[str, int],
     computations: Sequence[Computation],
+    loops: Sequence[Sequence[Loop]] | None = None,
 ) -> list[DepVector]:
-    """All dependence direction vectors among computations of one atomic nest."""
+    """All dependence direction vectors among computations of one atomic nest.
+
+    ``loops``, when given, holds each computation's enclosing loops (aligned
+    with ``computations``); a pair that ``guarded_disjoint`` then proves never
+    touches one element yields no vector.
+    """
     vectors: set[tuple[str, ...]] = set()
-    for c1 in computations:
-        for c2 in computations:
+    for i1, c1 in enumerate(computations):
+        for i2, c2 in enumerate(computations):
             for a, b in access_pairs(c1, c2):
                 if _is_reduction_self_dep(c1, c2, a, b):
                     # associative accumulation: reorderable by construction
@@ -333,6 +457,9 @@ def nest_direction_vectors(
                 # — the mirrored, lexicographically-negative copy is redundant.
                 lead = next(s for s in vec if s != EQ)
                 if lead == GT:
+                    continue
+                if loops is not None and guarded_disjoint(
+                        loops[i1], c1, a, loops[i2], c2, b):
                     continue
                 vectors.add(vec)
     return [DepVector(v) for v in sorted(vectors)]
