@@ -11,6 +11,13 @@ reference itself one precision step below the configuration's (bfloat16,
 limit lies between the largest sound reading and the smallest control
 reading.  Writes ``bench/out/calibrate.<cell>.json`` and prints a summary.
 The benchmark's own runs never run the control.
+
+A serving cell (a configuration whose ``builder`` is ``serving``) is
+calibrated by ``serving.calibrate``: each seed serves the cell's traffic
+for a window of ``--window`` seconds, and its served tokens are read
+against the reference, and, on the first ``--control-seeds`` seeds, so are
+the tokens that the control and each fault the configuration lists put
+first at the same positions.
 """
 from __future__ import annotations
 
@@ -65,18 +72,45 @@ def readings(cell, progs, fns, seeds) -> dict[str, list[float]]:
     return out
 
 
+def calibrate_serving(cell: harness.Cell, args) -> int:
+    from bench import serving
+
+    t0 = time.perf_counter()
+    seeds = [args.first_seed + 7919 * k for k in range(args.seeds)]
+    per_seed = serving.calibrate(cell, seeds, seeds[:args.control_seeds], args.window)
+    names = sorted({k for r in per_seed.values() for k in r
+                    if k not in ("failed_requests", "checked_tokens", "checked_lengths")})
+    summary = {"logit_gap": {"sound_max": max(r["logit_gap"] for r in per_seed.values()),
+                             "limit": cell.config["limits"]["logit_gap"]}}
+    for name in names:
+        if name != "logit_gap":
+            summary[name] = {"min": min(r[name] for r in per_seed.values() if name in r)}
+    harness.OUT_DIR.mkdir(parents=True, exist_ok=True)
+    with open(harness.OUT_DIR / f"calibrate.{cell.name}.json", "w") as f:
+        json.dump({"cell": cell.name, "seeds": seeds, "window_s": args.window,
+                   "readings": {str(k): v for k, v in per_seed.items()}, "summary": summary,
+                   "seconds": time.perf_counter() - t0, "device": harness.device_info()},
+                  f, indent=1)
+    print(json.dumps(summary))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, default=12)
     ap.add_argument("--control-seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=4_000_000_001)
+    ap.add_argument("--window", type=float, default=20.0,
+                    help="seconds of traffic per seed (serving cells)")
     args = ap.parse_args(argv)
     cell = harness.load_cell(args.workload)
     if harness.device_info()["platform"] != "tpu":
         print("calibrate: needs a TPU", file=sys.stderr)
         return 2
     harness.use_compile_cache()
+    if "builder" in cell.config:
+        return calibrate_serving(cell, args)
     harness.use_precision(cell.config)
     t0 = time.perf_counter()
     progs = harness.build_programs(cell.config, cell.traffic)

@@ -6,7 +6,9 @@ a configuration, a traffic mix or a metric by adding files:
 
 * ``BENCHMARK.json`` names the cell's configuration and traffic;
 * ``bench/configs/<config>.json`` holds the programs, their sizes, input
-  ranges, operation counts of opaque calls and the correctness limits;
+  ranges, operation counts of opaque calls and the correctness limits; a
+  configuration with a ``builder`` of its own (``serving``) is run by
+  ``bench/<builder>.py`` instead of the window below;
 * ``bench/references/<config>.py`` is the plain reference of its programs;
 * ``bench/traffic/<traffic>.json`` holds the variant and how the window is
   divided;
@@ -109,6 +111,9 @@ def make_cell(spec: dict, name: str, config: str, config_file: str, traffic: str
     cell = Cell(name, chips, load_json(root / config_file),
                 load_json(root / "bench" / "traffic" / f"{traffic}.json"),
                 ref.reference, e2e, per_layer)
+    builder = cell.config.get("builder")
+    if builder is not None and not (root / "bench" / f"{builder}.py").is_file():
+        raise FileNotFoundError(f"{config}: no bench/{builder}.py for builder {builder!r}")
     for m in e2e + per_layer:
         mod = load_module(root / "bench" / "metrics" / f"{m['name']}.py",
                           f"bench_metric_{m['name'].replace('.', '_').replace('-', '_')}")
@@ -388,6 +393,13 @@ def device_info() -> dict:
     return {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
 
 
+def memory_stats() -> dict:
+    """The first device's memory counters, as the backend reports them."""
+    import jax
+
+    return jax.devices()[0].memory_stats() or {}
+
+
 def peak_bytes() -> int | None:
     import jax
 
@@ -439,7 +451,11 @@ def estimate(call: Callable[[], Any], block: Callable, budget_s: float = 0.2,
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
              compile_fn: Callable = compile_programs) -> dict:
-    """One run of ``cell``; returns the result object (the last line)."""
+    """One run of ``cell``; returns the result object (the last line).  A
+    configuration with a ``builder`` is run by ``bench/<builder>.py``'s ``run_cell``."""
+    if "builder" in cell.config:
+        runner = importlib.import_module(f"bench.{cell.config['builder']}")
+        return runner.run_cell(cell, seed, seconds, trace, t_start)
     dev = device_info()
     counter = CompileCounter()
     try:

@@ -13,10 +13,17 @@ synthetic trace; ``read_xplane`` turns the ``.xplane.pb`` file that
   to the busy time.  An op is named ``<program>:<HLO name>``: the program
   whose slice (``slice:<program>``) it ran in, and the HLO instruction's
   name without its shape and operands.
+  An op that no slice encloses (a serving cell has none) is named by the
+  module run it ran in instead, ``<module>(<program id>):<HLO name>``.
+  The same ranking over every op, the enclosed ones too, names what runs
+  inside the top-level loops (``nested_ops``).
 * Host spans are the benchmark's own ``jax.profiler.TraceAnnotation``s
-  (``window``, ``slice:<program>``, ``dispatch:<program>``, ``block``).
-  Each idle gap of a device is charged to the ``dispatch:`` or ``block``
-  span that overlaps it most.
+  (``window``, ``slice:<program>``, ``dispatch:<program>``, ``block``,
+  ``inputs``).  Each idle gap of a device is charged to the ``dispatch:``,
+  ``block`` or ``inputs`` span that overlaps it most.
+* Modules are the events of the ``XLA Modules`` line: one per run of a
+  compiled program, named ``<module>(<program id>)``.  The runs that lie
+  whole inside the window are summed by that name.
 """
 from __future__ import annotations
 
@@ -27,6 +34,7 @@ from collections import defaultdict
 from typing import Iterable, NamedTuple
 
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 WINDOW_SPAN = "window"
 SPAN_PREFIXES = ("dispatch:", "block", "inputs")
 SLICE_PREFIX = "slice:"
@@ -42,6 +50,7 @@ class Event(NamedTuple):
 class Trace(NamedTuple):
     devices: dict[str, list[Event]]  # device plane name -> its ops
     spans: list[Event]  # the benchmark's host spans
+    modules: dict[str, list[Event]] = {}  # device plane name -> its module runs
 
 
 def union(intervals: Iterable[tuple[float, float]]) -> list[tuple[float, float]]:
@@ -107,8 +116,11 @@ def top_level(ops: list[Event]) -> list[Event]:
 
 
 def reduce_trace(trace: Trace, top: int = 10) -> dict:
-    """Busy and window seconds, the ``top`` device ops by summed time and the
-    ``top`` host spans by the idle time charged to them.
+    """Busy and window seconds, the ``top`` device ops by summed time, the
+    ``top`` host spans by the idle time charged to them, the ``top`` ops by
+    summed time counting enclosed ones too, and per module the seconds and
+    count of its runs inside the window (``[name, s, runs]``, longest
+    first).
 
     The window is the host span named ``window``; without one it is the
     extent of all device ops.
@@ -124,15 +136,32 @@ def reduce_trace(trace: Trace, top: int = 10) -> dict:
     spans = Spans([s for s in trace.spans if s.name.startswith(SPAN_PREFIXES)])
     slices = Spans([s for s in trace.spans if s.name.startswith(SLICE_PREFIX)])
     op_time: dict[str, float] = defaultdict(float)
+    nested: dict[str, float] = defaultdict(float)
     idle: dict[str, float] = defaultdict(float)
+    mod_time: dict[str, list[float]] = defaultdict(lambda: [0.0, 0])
     busy_total = 0.0
-    for ops in trace.devices.values():
+    for dev, ops in trace.devices.items():
+        runs = trace.modules.get(dev, [])
+        for r in runs:
+            if r.start >= lo and r.end <= hi:
+                t = mod_time[r.name]
+                t[0] += r.end - r.start
+                t[1] += 1
+        modules = Spans(runs)
         inside = [Event(o.name, max(o.start, lo), min(o.end, hi)) for o in ops
                   if min(o.end, hi) > max(o.start, lo)]
-        for o in top_level(inside):
+
+        def named(o: Event) -> str:
             where = slices.at(o.start, o.end)
-            prog = where[len(SLICE_PREFIX):] if where != NO_SPAN else "?"
-            op_time[f"{prog}:{short_name(o.name)}"] += o.end - o.start
+            if where != NO_SPAN:
+                return f"{where[len(SLICE_PREFIX):]}:{short_name(o.name)}"
+            run = modules.at(o.start, o.end)
+            return f"{run if run != NO_SPAN else '?'}:{short_name(o.name)}"
+
+        for o in top_level(inside):
+            op_time[named(o)] += o.end - o.start
+        for o in inside:
+            nested[named(o)] += o.end - o.start
         busy = union((o.start, o.end) for o in inside)
         busy_total += sum(e - s for s, e in busy)
         for g in gaps(busy, lo, hi):
@@ -144,6 +173,9 @@ def reduce_trace(trace: Trace, top: int = 10) -> dict:
         "window_s": (hi - lo) / 1e9,
         "device_ops": [[k, v / n / 1e9] for k, v in rank(op_time)],
         "idle_gaps": [[k, v / n / 1e9] for k, v in rank(idle)],
+        "nested_ops": [[k, v / n / 1e9] for k, v in rank(nested)],
+        "modules": [[k, s / n / 1e9, c / n] for k, (s, c) in
+                    sorted(mod_time.items(), key=lambda kv: -kv[1][0])],
     }
 
 
@@ -161,12 +193,14 @@ def read_xplane(path: str) -> Trace:
 
     pd = ProfileData.from_file(path)
     devices: dict[str, list[Event]] = {}
+    modules: dict[str, list[Event]] = {}
     spans: list[Event] = []
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
             for line in plane.lines:
-                if line.name == OPS_LINE:
-                    devices[plane.name] = [
+                if line.name in (OPS_LINE, MODULES_LINE):
+                    into = devices if line.name == OPS_LINE else modules
+                    into[plane.name] = [
                         Event(e.name, e.start_ns, e.start_ns + e.duration_ns)
                         for e in line.events]
         elif plane.name.startswith("/host:"):
@@ -176,4 +210,4 @@ def read_xplane(path: str) -> Trace:
                           if e.name == WINDOW_SPAN
                           or e.name.startswith(SPAN_PREFIXES + (SLICE_PREFIX,))]
     devices = {k: v for k, v in devices.items() if v}
-    return Trace(devices, spans)
+    return Trace(devices, spans, {k: v for k, v in modules.items() if k in devices})

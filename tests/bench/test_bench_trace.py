@@ -84,3 +84,18 @@ def test_busy_is_averaged_over_devices():
 def test_a_trace_without_device_ops_is_refused():
     with pytest.raises(ValueError, match="no device op"):
         reduce_trace(Trace({}, [Event("window", 0.0, 1.0)]))
+
+
+def test_module_runs_name_ops_outside_slices_and_are_summed_whole():
+    ops = [Event("%while.3 = f32[8] while()", 1.0 * S, 3.0 * S),
+           Event("%fusion.2 = f32[8] fusion()", 1.5 * S, 2.0 * S),
+           Event("%fusion.9 = f32[8] fusion()", 3.5 * S, 3.9 * S)]
+    runs = [Event("jit__unknown(11)", 0.9 * S, 3.1 * S),
+            Event("jit__unknown(22)", 3.4 * S, 4.5 * S)]  # ends past the window
+    spans = [Event("window", 0.0, 4.0 * S), Event("dispatch:step", 0.0, 4.0 * S)]
+    r = reduce_trace(Trace({"/device:TPU:0": ops}, spans, {"/device:TPU:0": runs}))
+    assert r["device_ops"] == [["jit__unknown(11):while.3", pytest.approx(2.0)],
+                               ["jit__unknown(22):fusion.9", pytest.approx(0.4)]]
+    assert ["jit__unknown(11):fusion.2", pytest.approx(0.5)] in r["nested_ops"]
+    assert r["modules"] == [["jit__unknown(11)", pytest.approx(2.2), 1.0]]
+    assert reduce_trace(_trace())["modules"] == []
