@@ -13,12 +13,13 @@ from .llava_next_mistral_7b import CONFIG as _llava
 from .seamless_m4t_large_v2 import CONFIG as _seamless
 from .jamba_1_5_large_398b import CONFIG as _jamba
 from .xlstm_350m import CONFIG as _xlstm
+from .mellum2_12b_a2_5b import CONFIG as _mellum2
 
 ARCHS: dict[str, ModelConfig] = {
     c.name: c
     for c in (
         _mixtral, _qwen3moe, _minicpm, _danube, _qwen15,
-        _mistral_large, _llava, _seamless, _jamba, _xlstm,
+        _mistral_large, _llava, _seamless, _jamba, _xlstm, _mellum2,
     )
 }
 

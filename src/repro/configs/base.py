@@ -12,6 +12,20 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
 
+ATTN_KINDS = ("sliding", "full")
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of the rotary embedding (arXiv:2309.00071), with the
+    keys of a Hugging Face ``rope_parameters`` entry of ``rope_type`` yarn."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -30,12 +44,17 @@ class ModelConfig:
     top_k: int = 0
     moe_period: int = 1        # layer l is MoE iff l % moe_period == moe_offset
     moe_offset: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0      # experts 0..held-1 live here (0 = all of them)
 
     # attention
     window: int | None = None  # sliding-window size (None = full)
+    # attention kind of each layer, repeating, 'sliding' (the window) or
+    # 'full'; empty = every layer alike (``window`` on all of them in
+    # ``forward``; the serve path keeps full-length caches for them)
+    layer_types: tuple[str, ...] = ()
     qkv_bias: bool = False
     rope_theta: float = 1e6
+    yarn: Yarn | None = None   # YaRN on the 'full' layers' rotary embedding
 
     # hybrid (Jamba): one attention layer per `attn_period` layers, rest Mamba
     attn_period: int = 0       # 0 = every layer is attention
@@ -61,6 +80,20 @@ class ModelConfig:
     remat: str = "block"  # 'block' | 'none' | 'block_save_moe' (keep dispatch)
     seq_parallel: bool = False  # Megatron SP: seq-shard activations between
     #                             layers (RS+AG instead of all-reduce)
+
+    def __post_init__(self):
+        # accept the JSON forms (a list, a dict) and keep the config hashable
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if isinstance(self.yarn, dict):
+            object.__setattr__(self, "yarn", Yarn(**self.yarn))
+        if not set(self.layer_types) <= set(ATTN_KINDS):
+            raise ValueError(f"layer_types {self.layer_types} not all in {ATTN_KINDS}")
+        if self.layer_types and (self.attn_period or self.block_pattern):
+            raise ValueError("layer_types gives the attention kinds of an all-attention stack")
+        if "sliding" in self.layer_types and not self.window:
+            raise ValueError("a 'sliding' layer needs a window")
+        if not 0 <= self.experts_held <= self.n_experts:
+            raise ValueError(f"experts_held {self.experts_held} of {self.n_experts}")
 
     # --- derived -------------------------------------------------------------
     @property
@@ -89,6 +122,34 @@ class ModelConfig:
         return self.is_moe and l % self.moe_period == self.moe_offset
 
     @property
+    def held_experts(self) -> int:
+        """Experts whose weights this chip holds: 0..held_experts-1."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def period(self) -> int:
+        """Layers of the repeating pattern of parameter structures the layer
+        stack scans over, one parameter stack per position (0: every layer
+        has layer 0's parameters, one stack; attention kinds of
+        ``layer_types`` differ in cache and rotary embedding only)."""
+        if self.family == "hybrid":
+            return self.attn_period
+        return len(self.block_pattern)
+
+    def attn_kind(self, l: int) -> str | None:
+        """'sliding' or 'full' for layer l, None without ``layer_types``."""
+        return self.layer_types[l % len(self.layer_types)] if self.layer_types else None
+
+    def layer_window(self, l: int) -> int | None:
+        """The window layer l's attention sees: ``window`` on a 'sliding'
+        layer, None on a 'full' one; without ``layer_types``, ``window``."""
+        return None if self.attn_kind(l) == "full" else self.window
+
+    def layer_yarn(self, l: int) -> Yarn | None:
+        """YaRN parameters of layer l's rotary embedding (full layers only)."""
+        return self.yarn if self.attn_kind(l) == "full" else None
+
+    @property
     def supports_long_context(self) -> bool:
         """Sub-quadratic path exists: SWA, SSM, or hybrid."""
         if self.family in ("ssm", "hybrid"):
@@ -98,19 +159,22 @@ class ModelConfig:
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
         pat = self.block_pattern[:4] if self.block_pattern else ()
+        period = self.attn_period or len(self.layer_types)
         return replace(
             self,
-            n_layers=max(2, min(4, self.n_layers)) if not self.attn_period
-            else self.attn_period,  # keep one full hybrid period
+            n_layers=period or max(2, min(4, self.n_layers)),  # one whole period
             d_model=128,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads < self.n_heads else 4,
             d_head=32,
             d_ff=256 if self.d_ff else 0,
             vocab=512,
-            n_experts=min(self.n_experts, 4),
+            n_experts=min(self.n_experts, 4) if not self.experts_held else 8,
             top_k=min(self.top_k, 2),
-            window=min(self.window, 64) if self.window else None,
+            # fewer held than routed, top-k within what is held
+            experts_held=4 if self.experts_held else 0,
+            # per-kind layers: a window that test prompts outgrow
+            window=(16 if self.layer_types else min(self.window, 64)) if self.window else None,
             enc_layers=2 if self.enc_layers else 0,
             frontend_len=8 if self.frontend else self.frontend_len,
             mamba_d_state=8,

@@ -6,5 +6,4 @@ backend-switching public API (xla / pallas_interpret / pallas).
 from . import ops, ref  # noqa: F401
 from .flash_attention import flash_attention  # noqa: F401
 from .gemm import gemm  # noqa: F401
-from .moe_gmm import grouped_matmul  # noqa: F401
 from .rmsnorm import rmsnorm  # noqa: F401

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from . import ref
 from .flash_attention import flash_attention as _flash
 from .gemm import gemm as _gemm
-from .moe_gmm import grouped_matmul as _gmm
 from .rmsnorm import rmsnorm as _rmsnorm
 
 BACKEND = "xla"
@@ -55,14 +54,6 @@ def attention(q, k, v, *, causal=True, window=None, q_offset=0,
     bq, bk_ = tile or (128, 128)
     return _flash(q, k, v, causal=causal, window=window, q_offset=q_offset,
                   block_q=bq, block_k=bk_, interpret=interp)
-
-
-def grouped_matmul(x, w, *, tile=None, backend=None):
-    pallas, interp = _use_pallas(backend)
-    if not pallas:
-        return ref.grouped_matmul(x, w)
-    bc, bf, bd = tile or (128, 128, 128)
-    return _gmm(x, w, block_c=bc, block_f=bf, block_d=bd, interpret=interp)
 
 
 def rmsnorm(x, gamma, *, eps=1e-6, backend=None):

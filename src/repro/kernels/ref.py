@@ -10,26 +10,32 @@ def matmul(x, y):
 
 
 def attention(q, k, v, *, causal=True, window=None, q_offset=0):
-    """q: (BH, Sq, D); k, v: (BHkv, Skv, D), GQA by head-group repetition."""
+    """q: (BH, Sq, D); k, v: (BHkv, Skv, D).  GQA: the query heads of one
+    key-value head read its keys and values as they are (no copy per query
+    head), in their own type, with float32 scores and sums.  ``q_offset``,
+    the position of the first query, is one for all or one per key-value
+    batch row (BHkv,)."""
     bhq, sq, d = q.shape
     bhkv, skv, _ = k.shape
-    group = bhq // bhkv
-    if group > 1:
-        k = jnp.repeat(k, group, axis=0)
-        v = jnp.repeat(v, group, axis=0)
-    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32), k.astype(jnp.float32))
+    qg = q.reshape(bhkv, bhq // bhkv, sq, d)
+    s = jnp.einsum("bgqd,bkd->bgqk", qg, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
     s = s / (d ** 0.5)
-    q_pos = q_offset + jnp.arange(sq)[:, None]
+    q_pos = jnp.asarray(q_offset)[..., None, None] + jnp.arange(sq)[:, None]
     k_pos = jnp.arange(skv)[None, :]
-    mask = jnp.ones((sq, skv), dtype=bool)
+    mask = jnp.ones(q_pos.shape[:-1] + (skv,), dtype=bool)
     if causal:
         mask &= q_pos >= k_pos
     if window is not None:
         mask &= (q_pos - k_pos) < window
-    s = jnp.where(mask[None], s, -jnp.inf)
+    if mask.ndim == 3:  # one offset per row
+        mask = mask[:, None]
+    s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(mask[None], p, 0.0)  # rows with no visible keys -> 0
-    return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)
+    p = jnp.where(mask, p, 0.0)  # rows with no visible keys -> 0
+    o = jnp.einsum("bgqk,bkd->bgqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(bhq, sq, d).astype(q.dtype)
 
 
 def attention_chunked(q, k, v, *, causal=True, window=None, q_offset=0,
@@ -101,13 +107,6 @@ def attention_chunked(q, k, v, *, causal=True, window=None, q_offset=0,
     out = jax.lax.map(q_block, jnp.arange(nq))  # (nq, BH, bq, d)
     out = out.transpose(1, 0, 2, 3).reshape(bhq, nq * bq, d)
     return out[:, :sq]
-
-
-def grouped_matmul(x, w):
-    """x: (E, C, D); w: (E, D, F)."""
-    return jnp.einsum(
-        "ecd,edf->ecf", x.astype(jnp.float32), w.astype(jnp.float32)
-    ).astype(x.dtype)
 
 
 def rmsnorm(x, gamma, *, eps=1e-6):

@@ -12,8 +12,9 @@ each layer determines which axis its parallel loop maps to.
   expert weights  (E, D, F): EP (model, None, None) when E%model==0,
                              else TP on the trailing dims
   embed (V, D): vocab-parallel when V%model==0 else feature-parallel
-  batch dims: (pod, data); KV caches: batch -> DP, heads -> model when
-              divisible; batch=1 decode shards the cache *sequence* (SP)
+  batch dims: (pod, data); KV caches (L, B, KV, S, dh): batch -> DP,
+              heads -> model when divisible; batch=1 decode shards the
+              cache *sequence* (SP)
 """
 from __future__ import annotations
 
@@ -171,23 +172,21 @@ def state_specs(cfg: ModelConfig, mesh, state_shape: Pytree) -> Pytree:
             return NamedSharding(
                 mesh, P(dp if _div(b, dpn) else None, None,
                         "model" if _div(leaf.shape[2], m) else None))
-        # KV caches: (L, B, S, KV, dh) or mamba/mlstm states (L, B, ...)
+        # KV caches: (L, B, KV, S, dh), head-major, the sequence at dim 3;
+        # mamba/mlstm states (L, B, ...) keep their dim 2 whole
         if nd >= 3:
             b = leaf.shape[1]
+            seq = 3 if nd == 5 else 2
             spec = [None] * nd
             if _div(b, dpn):
                 spec[1] = dp
+            elif nd >= 4 and _div(leaf.shape[seq], dpn):
+                # SP: batch too small -> shard the sequence dim of the cache
+                spec[seq] = dp
+            if spec[1] is not None or nd >= 4:
                 # shard a feature dim over model when possible
                 for d in range(2, nd):
-                    if d != 2 and _div(leaf.shape[d], m):
-                        spec[d] = "model"
-                        break
-            elif nd >= 4:
-                # SP: batch too small -> shard the sequence dim of the cache
-                if _div(leaf.shape[2], dpn):
-                    spec[2] = dp
-                for d in range(3, nd):
-                    if _div(leaf.shape[d], m):
+                    if d != seq and _div(leaf.shape[d], m):
                         spec[d] = "model"
                         break
             return NamedSharding(mesh, P(*spec))

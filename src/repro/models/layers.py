@@ -10,8 +10,9 @@ Memory-hierarchy notes (TPU adaptation, see DESIGN.md):
     materialize (B, S, d_inner, d_state), which no HBM holds at the assigned
     shapes; chunking bounds the working set to (B, Q, d_inner, d_state) per
     step, the same a-priori working-set reasoning the paper applies to L1.
-  * MoE dispatch is sort-based with static capacity (EP-shardable dense
-    (E, C, D) buckets) rather than GPU-style CSR block sparsity.
+  * MoE is dropless: the (token, expert) pairs of the experts held here,
+    sorted by expert, go through one grouped product per weight
+    (``jax.lax.ragged_dot``), however unevenly they are routed.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ..configs.base import ModelConfig
+from ..configs.base import ModelConfig, Yarn
 from ..kernels import ops
 
 Params = dict[str, Any]
@@ -75,13 +76,39 @@ def _dense_init(key, shape, dtype, scale=None):
     return (normal(key, shape) * s).astype(dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, Dh); positions: broadcastable to (..., S)."""
+def yarn_frequencies(dh: int, theta: float, yarn: Yarn) -> tuple[np.ndarray, float]:
+    """Inverse frequencies (dh/2,) and cos/sin scale of YaRN-scaled RoPE
+    (arXiv:2309.00071; Hugging Face ``_compute_yarn_parameters``): below
+    the correction range the frequencies ``theta ** (-2i / dh)`` are kept,
+    above it they are divided by ``factor``, and a linear ramp blends the
+    two in between."""
+    def correction_dim(rotations: float) -> float:
+        return (dh * math.log(yarn.original_max_position_embeddings
+                              / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dh - 1)
+    if low == high:
+        high += 0.001
+    extrapolation = 1.0 / theta ** (np.arange(0, dh, 2, dtype=np.float64) / dh)
+    ramp = np.clip((np.arange(dh // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    inv = extrapolation / yarn.factor * ramp + extrapolation * (1.0 - ramp)
+    return inv.astype(np.float32), float(yarn.attention_factor)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float, yarn: Yarn | None = None) -> jax.Array:
+    """x: (..., S, H, Dh); positions: broadcastable to (..., S).  With
+    ``yarn``, YaRN's frequencies and its scale on cos and sin."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs, scale = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half), 1.0
+    else:
+        freqs, scale = yarn_frequencies(dh, theta, yarn)
     angles = positions[..., :, None, None].astype(jnp.float32) * freqs  # (...,S,1,half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -106,29 +133,25 @@ def init_attention(key, cfg: ModelConfig, dtype) -> Params:
     return p
 
 
-def attention(
-    x: jax.Array,  # (B, S, D)
-    p: Params,
-    cfg: ModelConfig,
-    *,
-    positions: jax.Array,  # (B, S) absolute positions (rope)
-    causal: bool = True,
-    cache: tuple[jax.Array, jax.Array] | None = None,  # (B, S_cache, KV, Dh)
-    write_pos: jax.Array | int = 0,   # cache slot to write (ring for SWA)
-    attn_offset: jax.Array | int = 0,  # q_offset for masking vs cache slots
-    memory: jax.Array | None = None,  # (B, S_mem, D) for cross-attention
-):
-    """Sequence attention (cache=None) or single-step decode (cache given).
+def ring_write(ring: jax.Array, new: jax.Array, clen, n_new) -> jax.Array:
+    """Write positions ``clen .. clen + n_new - 1`` of ``new`` (..., s, Dh)
+    into a ring (..., W, Dh) that holds position p at slot p % W (the
+    sequence is the second-to-last axis): each slot takes the latest of
+    them that maps to it, or keeps what it held."""
+    w, s = ring.shape[-2], new.shape[-2]
+    end = clen + n_new
+    pos = end - 1 - jnp.mod(end - 1 - jnp.arange(w), w)  # latest position per slot
+    src = pos - clen
+    rows = jnp.take(new, jnp.clip(src, 0, s - 1), axis=-2)
+    return jnp.where((src >= 0)[:, None], rows.astype(ring.dtype), ring)
 
-    SWA decode uses a ring buffer of size ``window``: keys are roped at their
-    absolute positions *before* being written, so slot order is irrelevant
-    (softmax is permutation-invariant); ``attn_offset = min(len, window-1)``
-    masks not-yet-written slots via the causal test and the ring itself
-    bounds the window.
-    """
+
+def project_qkv(x: jax.Array, p: Params, cfg: ModelConfig, memory: jax.Array | None = None):
+    """Queries (B, S, H, Dh) and keys and values (B, S_kv, KV, Dh) of x (B,
+    S, D), the keys and values of ``memory`` for cross-attention; not yet
+    roped."""
     b, s, d = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-
     q = x @ p["wq"] + (p["bq"] if "bq" in p else 0.0)
     src = memory if memory is not None else x
     k = src @ p["wk"] + (p["bk"] if "bk" in p else 0.0)
@@ -136,34 +159,72 @@ def attention(
     q = q.reshape(b, s, h, dh)
     k = k.reshape(b, -1, kv, dh)
     v = v.reshape(b, -1, kv, dh)
-
-    if memory is None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-
     # layout hints: heads over 'model' where they divide (constrain drops the
     # axis otherwise -> KV replicates over model for GQA kv < mesh)
     q = constrain(q, ("pod", "data"), None, "model", None)
     k = constrain(k, ("pod", "data"), None, "model", None)
     v = constrain(v, ("pod", "data"), None, "model", None)
+    return q, k, v
 
-    new_cache = None
+
+def attention(
+    x: jax.Array,  # (B, S, D)
+    p: Params,
+    cfg: ModelConfig,
+    *,
+    positions: jax.Array,  # (B, S) absolute positions (rope)
+    causal: bool = True,
+    cache: tuple[jax.Array, jax.Array] | None = None,  # (B, KV, S_cache, Dh)
+    clen: jax.Array | int = 0,  # positions the cache holds before this call
+    n_new: jax.Array | int | None = None,  # real tokens of the S (the rest padding)
+    memory: jax.Array | None = None,  # (B, S_mem, D) for cross-attention
+    window: int | None = None,
+    yarn: Yarn | None = None,
+):
+    """Sequence attention (cache=None) or attention over a KV cache, which
+    is head-major (B, KV, S_cache, Dh) so that attention reads it as it is.
+
+    A cache no longer than ``window`` is a ring: position p sits at slot
+    p % size, keys roped at their absolute positions *before* being
+    written, so slot order is irrelevant (softmax is permutation-invariant).
+    One token attends over the ring (``q_offset = min(clen, size - 1)``
+    masks slots not yet written, and the ring itself bounds the window);
+    several tokens (a prefill into an empty ring) attend over themselves
+    under the window, and the last ``window`` real ones stay in the ring.
+    A longer cache holds every position and applies no window.
+    """
+    b, s, _ = x.shape
+    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q, k, v = project_qkv(x, p, cfg, memory)
+    if memory is None:
+        q = rope(q, positions, cfg.rope_theta, yarn)
+        k = rope(k, positions, cfg.rope_theta, yarn)
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # (B, KV, S_kv, Dh)
+
+    new_cache, q_offset = None, 0
     if cache is not None:
-        ck, cv = cache  # (B, S_cache, KV, Dh)
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), write_pos, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), write_pos, axis=1)
-        k, v = ck, cv
+        ck, cv = cache
+        size = ck.shape[2]
+        if window is not None and size <= window and s > 1:
+            n = s if n_new is None else n_new
+            ck, cv = ring_write(ck, k, clen, n), ring_write(cv, v, clen, n)
+        else:
+            ring = window is not None and size <= window
+            wpos = jnp.mod(clen, size) if ring else clen
+            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), wpos, axis=2)
+            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), wpos, axis=2)
+            k, v = ck, cv
+            q_offset = jnp.minimum(clen, size - 1) if ring else clen
+            window = None
         new_cache = (ck, cv)
 
     # fold heads into batch: q (B*H, S, Dh); k/v (B*KV, Skv, Dh)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * kv, -1, dh)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * kv, -1, dh)
     of = ops.attention(
-        qf, kf, vf,
+        qf, k.reshape(b * kv, -1, dh), v.reshape(b * kv, -1, dh),
         causal=causal and memory is None,
-        window=cfg.window if (memory is None and cache is None) else None,
-        q_offset=attn_offset if cache is not None else 0,
+        window=window if memory is None else None,
+        q_offset=q_offset,
     )
     out = of.reshape(b, h, s, dh).transpose(0, 2, 1, 3).reshape(b, s, h * dh)
     return out @ p["wo"], new_cache
@@ -187,64 +248,73 @@ def dense_ffn(x: jax.Array, p: Params) -> jax.Array:
 
 
 def init_moe_ffn(key, cfg: ModelConfig, dtype) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts`` and the weights of the experts
+    held here (``held_experts``)."""
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.held_experts
     ks = jax.random.split(key, 4)
     return {
-        "router": _dense_init(ks[0], (d, e), jnp.float32),
+        "router": _dense_init(ks[0], (d, cfg.n_experts), jnp.float32),
         "wg": _dense_init(ks[1], (e, d, f), dtype),
         "wu": _dense_init(ks[2], (e, d, f), dtype),
         "wd": _dense_init(ks[3], (e, f, d), dtype),
     }
 
 
-def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
-    c = int(math.ceil(cfg.capacity_factor * n_tokens * cfg.top_k / cfg.n_experts))
-    return max(8, -(-c // 8) * 8)  # round up to sublane multiple
+def route(logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """Top-k routing over router logits (T, E): the softmax over all
+    experts at the k largest, renormalized over them (the softmax of the k
+    largest logits); returns (gates, experts), each (T, k)."""
+    top, experts = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(top, axis=-1), experts
 
 
-def moe_ffn(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
-    """Top-k token-choice MoE with static capacity (sort-based dispatch).
+def moe_ffn(x: jax.Array, p: Params, cfg: ModelConfig, *, first: int = 0,
+            valid: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
+    """Dropless top-k mixture of experts over the experts this chip holds.
 
-    x: (T, D) -> (T, D).  Dropped tokens (capacity overflow) contribute 0,
-    matching GShard/Mixtral-style capacity semantics.
+    x: (T, D) -> (T, D).  Each token is routed over all ``n_experts``:
+    softmax of its router logits, the top k, renormalized over the k (the
+    softmax of the k largest logits).  For every (token, expert) pair whose
+    expert is held here, experts ``first`` .. ``first + held - 1`` with
+    ``held`` the leading dim of ``p["wg"]``, the layer adds ``gate *
+    (silu(x Wg_e) * (x Wu_e)) Wd_e``; a pair of another expert adds nothing
+    here (its chip computes it).  The pairs are sorted by expert and each
+    weight is one grouped product over them (``jax.lax.ragged_dot``): no
+    capacity, no token dropped.  ``valid`` (T,) marks the tokens that route
+    (False: padding).
+
+    Returns ``(y, counts)``: counts (2,) int32 are the pairs computed and
+    the held experts that received at least one token.
     """
     t, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    c = moe_capacity(cfg, t)
+    held, k = p["wg"].shape[0], cfg.top_k
 
-    logits = (x.astype(jnp.float32)) @ p["router"]  # (T, E)
-    gates, experts = jax.lax.top_k(logits, k)  # (T, K)
-    gates = jax.nn.softmax(gates, axis=-1).astype(x.dtype)
+    # the router is float32 by design: its product too (a TPU's default
+    # would round its operands to bfloat16 and flip near-tied experts)
+    logits = jnp.dot(x.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST)
+    gates, experts = route(logits, k)
+    local = experts - first
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine &= valid[:, None]
 
-    fe = experts.reshape(-1)  # (T*K,)
-    ft = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
-    fg = gates.reshape(-1)
-    order = jnp.argsort(fe)  # stable
-    se, st, sg = fe[order], ft[order], fg[order]
-
-    counts = jnp.zeros((e,), jnp.int32).at[fe].add(1)
-    starts = jnp.cumsum(counts) - counts
-    pos = jnp.arange(t * k, dtype=jnp.int32) - starts[se]
-    keep = pos < c
-    dest = jnp.where(keep, se * c + pos, e * c)  # overflow slot e*c
-
-    disp = jnp.zeros((e * c + 1, d), x.dtype).at[dest].set(x[st])
-    disp = constrain(disp[: e * c].reshape(e, c, d), "model", None, None)  # EP
-    # name the dispatched buckets so the 'block_save_moe' remat policy can
-    # keep them: recomputing the dispatch in the backward repeats its
-    # all-to-all-class collectives (3x the EP bytes)
-    disp = checkpoint_name(disp, "moe_dispatch")
-
-    h = ops.grouped_matmul(disp, p["wg"])
-    u = ops.grouped_matmul(disp, p["wu"])
-    y = ops.grouped_matmul(jax.nn.silu(h) * u, p["wd"])  # (E, C, D)
-    y = constrain(y, "model", None, None)
-    y = checkpoint_name(y, "moe_expert_out")
-
-    y_flat = jnp.concatenate([y.reshape(e * c, d), jnp.zeros((1, d), y.dtype)], 0)
-    contrib = y_flat[dest] * (sg * keep.astype(sg.dtype))[:, None]
-    contrib = constrain(contrib, ("pod", "data"), None)
-    return jnp.zeros((t, d), x.dtype).at[st].add(contrib.astype(x.dtype))
+    key = jnp.where(mine, local, held).reshape(-1)  # (T*K,); not here -> held
+    order = jnp.argsort(key)  # stable: by expert, then by token
+    tok = order // k
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    kept = jnp.take(mine.reshape(-1), order)
+    gate = jnp.where(kept, jnp.take(gates.reshape(-1), order), 0.0)
+    with jax.named_scope("moe.experts"):
+        xs = checkpoint_name(jnp.take(x, tok, axis=0), "moe_dispatch")
+        hg = jax.lax.ragged_dot(xs, p["wg"], sizes)
+        hu = jax.lax.ragged_dot(xs, p["wu"], sizes)
+        y = jax.lax.ragged_dot((jax.nn.silu(hg) * hu).astype(x.dtype), p["wd"], sizes)
+        y = checkpoint_name(y, "moe_expert_out")
+    # rows past the held experts' groups belong to no product: zero them
+    contrib = jnp.where(kept[:, None], y.astype(jnp.float32), 0.0) * gate[:, None]
+    out = jnp.zeros((t, d), jnp.float32).at[tok].add(contrib).astype(x.dtype)
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out, counts
 
 
 # ---------------------------------------------------------------------------

@@ -90,9 +90,7 @@ def model_contractions(cfg: ModelConfig, seq: int, batch: int) -> dict[str, tupl
     }
     if f:
         if cfg.is_moe:
-            from .layers import moe_capacity
-
-            c = moe_capacity(cfg, t)
+            c = max(1, -(-t * cfg.top_k // cfg.n_experts))  # tokens per expert, evenly routed
             out["expert_ffn_in"] = (c, f, d)   # per expert
             out["expert_ffn_out"] = (c, d, f)
         else:

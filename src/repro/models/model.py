@@ -3,7 +3,10 @@
 Families:
   dense / moe / vlm  — decoder-only transformer (GQA, SWA, optional QKV bias,
                        optional MoE FFN), layers run under ``lax.scan`` over
-                       stacked parameters (compile once per unique layer).
+                       stacked parameters (compile once per unique layer);
+                       layers of different attention kinds (``layer_types``:
+                       sliding or full) share the stack and the scan body,
+                       which switches on each layer's kind.
   audio              — encoder-decoder (stub frame embeddings -> encoder;
                        text decoder with cross-attention).
   hybrid (Jamba)     — periodic layer pattern (1 attention : 7 Mamba, MoE on
@@ -17,12 +20,13 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..configs.base import ModelConfig
+from ..configs.base import ModelConfig, Yarn
 from . import layers as L
 
 Params = dict[str, Any]
@@ -76,12 +80,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         p["lm_head"] = (L.normal(keys[-2], (cfg.d_model, cfg.vocab))
                         * (1.0 / math.sqrt(cfg.d_model))).astype(dt)
 
-    if cfg.family in ("dense", "moe", "vlm"):
-        # scan needs identical treedefs: every layer is layer 0's kind
-        p["layers"] = jax.lax.map(
-            lambda k: _init_block(k, cfg, "attn", cfg.layer_is_moe(0), dt),
-            keys[:cfg.n_layers])
-    elif cfg.family == "audio":
+    if cfg.family == "audio":
         def dec_block(k):
             blk = _init_block(k, cfg, "attn", False, dt)
             blk["norm_x"] = jnp.ones((cfg.d_model,), dt)
@@ -93,9 +92,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         p["decoder"] = jax.lax.map(
             dec_block, keys[cfg.enc_layers:cfg.enc_layers + cfg.n_layers])
         p["enc_final_norm"] = jnp.ones((cfg.d_model,), dt)
-    elif cfg.family in ("hybrid", "ssm"):
+    elif cfg.period:
         # layer g * period + pos is stacked at index g of position pos
-        period = cfg.attn_period if cfg.family == "hybrid" else len(cfg.block_pattern)
+        period = cfg.period
         n_periods = cfg.n_layers // period
         grid = keys[:n_periods * period].reshape(n_periods, period, *keys.shape[1:])
         p["periods"] = [
@@ -104,38 +103,72 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             for pos in range(period)
         ]
     else:
-        raise ValueError(cfg.family)
+        # scan needs identical treedefs: every layer is layer 0's kind
+        p["layers"] = jax.lax.map(
+            lambda k: _init_block(k, cfg, "attn", cfg.layer_is_moe(0), dt),
+            keys[:cfg.n_layers])
     return p
 
 
 # ---------------------------------------------------------------------------
-# block application (sequence form)
+# block application
 # ---------------------------------------------------------------------------
-def _apply_block(
-    x, blk: Params, cfg: ModelConfig, kind: str, use_moe: bool,
-    positions, causal=True, memory=None,
-    state=None, write_pos=0, attn_offset=0,
-):
-    """Returns (x_out, new_state)."""
+class Spec(NamedTuple):
+    """What a layer position computes: its mixer, whether its FFN is the
+    expert layer, its attention window and its rotary scaling."""
+
+    kind: str
+    moe: bool
+    window: int | None
+    yarn: Yarn | None
+
+
+def layer_spec(cfg: ModelConfig, l: int) -> Spec:
+    return Spec(cfg.layer_kind(l), cfg.layer_is_moe(l), cfg.layer_window(l), cfg.layer_yarn(l))
+
+
+def stack_kinds(cfg: ModelConfig) -> tuple[list[Spec], np.ndarray, np.ndarray]:
+    """The layers of one stacked scan (every family but the period-scanned
+    ones): the distinct specs they take (one per attention kind), and per
+    layer which spec and its index among that spec's layers.  Every layer
+    shares layer 0's parameter structure (expert layer or not)."""
+    moe = cfg.layer_is_moe(0) and cfg.family != "audio"
+    specs: list[Spec] = []
+    which, index = [], []
+    for layer in range(cfg.n_layers):
+        sp = layer_spec(cfg, layer)._replace(moe=moe)
+        if sp not in specs:
+            specs.append(sp)
+        which.append(specs.index(sp))
+        index.append(which.count(which[-1]) - 1)
+    return specs, np.asarray(which, np.int32), np.asarray(index, np.int32)
+
+
+def _switch(which, branches, *operands):
+    """``branches[which](*operands)``: a ``lax.switch`` on the layer's kind
+    when the stack has several, the one branch inline otherwise."""
+    if len(branches) == 1:
+        return branches[0](*operands)
+    return jax.lax.switch(which, branches, *operands)
+
+
+def _mixer(x, blk: Params, cfg: ModelConfig, spec: Spec, positions, causal=True,
+           memory=None, state=None, clen=0, n_new=None):
+    """The block's first half, residual included: (x_out, new_state)."""
     from ..kernels import ops
 
     sp = cfg.seq_parallel and x.shape[1] > 1
-
-    def _sp(t):
-        # Megatron SP: sub-block outputs reduce-scatter onto the sequence dim
-        # (1x ring bytes); the next column-parallel matmul all-gathers.
-        return L.constrain(t, ("pod", "data"), "model", None) if sp else t
-
     normed = ops.rmsnorm(x, blk["norm1"], eps=cfg.norm_eps)
     new_state = None
-    if kind == "attn":
-        cache = state
-        att, new_state = L.attention(
-            normed, blk["mixer"], cfg, positions=positions, causal=causal,
-            cache=cache, write_pos=write_pos, attn_offset=attn_offset,
-            memory=None,
-        )
-        x = x + _sp(att)
+    if spec.kind == "attn":
+        with jax.named_scope("attn.sliding" if spec.window else "attn.full"):
+            att, new_state = L.attention(
+                normed, blk["mixer"], cfg, positions=positions, causal=causal,
+                cache=state, clen=clen, n_new=n_new, window=spec.window, yarn=spec.yarn,
+            )
+        # Megatron SP: sub-block outputs reduce-scatter onto the sequence dim
+        # (1x ring bytes); the next column-parallel matmul all-gathers.
+        x = x + (L.constrain(att, ("pod", "data"), "model", None) if sp else att)
         if memory is not None:  # cross-attention sub-block (enc-dec decoder)
             normed_x = ops.rmsnorm(x, blk["norm_x"], eps=cfg.norm_eps)
             cross, _ = L.attention(
@@ -143,24 +176,44 @@ def _apply_block(
                 causal=False, memory=memory,
             )
             x = x + cross
-    elif kind == "mamba":
+    elif spec.kind == "mamba":
         out, new_state = L.mamba(normed, blk["mixer"], cfg, state=state)
         x = x + out
-    elif kind == "mlstm":
+    elif spec.kind == "mlstm":
         out, new_state = L.mlstm(normed, blk["mixer"], cfg, state=state)
         x = x + out
-    elif kind == "slstm":
+    elif spec.kind == "slstm":
         out, new_state = L.slstm(normed, blk["mixer"], cfg, state=state)
         x = x + out
-    if cfg.d_ff:
-        normed2 = ops.rmsnorm(x, blk["norm2"], eps=cfg.norm_eps)
-        if use_moe:
-            b, s, d = normed2.shape
-            y = L.moe_ffn(normed2.reshape(b * s, d), blk["ffn"], cfg).reshape(b, s, d)
-        else:
-            y = L.dense_ffn(normed2, blk["ffn"])
-        x = x + _sp(y)
     return x, new_state
+
+
+def _ffn(x, blk: Params, cfg: ModelConfig, spec: Spec, valid=None):
+    """The block's second half, residual included, on x (..., D): (x_out,
+    expert counts (2,) int32, zero without experts).  ``valid`` (the
+    tokens of x, flattened) marks the tokens the expert layer routes."""
+    from ..kernels import ops
+
+    counts = jnp.zeros((2,), jnp.int32)
+    if not cfg.d_ff:
+        return x, counts
+    normed = ops.rmsnorm(x, blk["norm2"], eps=cfg.norm_eps)
+    if spec.moe:
+        y, counts = L.moe_ffn(normed.reshape(-1, x.shape[-1]), blk["ffn"], cfg, valid=valid)
+        y = y.reshape(x.shape)
+    else:
+        y = L.dense_ffn(normed, blk["ffn"])
+    if cfg.seq_parallel and x.ndim == 3 and x.shape[1] > 1:
+        y = L.constrain(y, ("pod", "data"), "model", None)
+    return x + y, counts
+
+
+def _apply_block(x, blk: Params, cfg: ModelConfig, spec: Spec, positions, causal=True,
+                 memory=None, state=None, clen=0, n_new=None, valid=None):
+    """Returns (x_out, new_state, expert counts)."""
+    x, new_state = _mixer(x, blk, cfg, spec, positions, causal, memory, state, clen, n_new)
+    x, counts = _ffn(x, blk, cfg, spec, valid)
+    return x, new_state, counts
 
 
 # ---------------------------------------------------------------------------
@@ -206,34 +259,24 @@ def forward(cfg: ModelConfig, params: Params, batch: dict[str, jax.Array]) -> ja
             return jax.checkpoint(fn, policy=policy)
         return fn
 
-    if cfg.family in ("dense", "moe", "vlm"):
-        is_moe = cfg.layer_is_moe(0)
-
-        @_maybe_remat
-        def body(xc, blk):
-            out, _ = _apply_block(xc, blk, cfg, "attn", is_moe, positions)
-            return out, None
-
-        x, _ = jax.lax.scan(body, x, params["layers"])
-    elif cfg.family == "audio":
+    if cfg.family == "audio":
         memory = encode(cfg, params, batch["embeds"])
+        spec = layer_spec(cfg, 0)._replace(moe=False)
 
         @_maybe_remat
         def body(xc, blk):
-            out, _ = _apply_block(xc, blk, cfg, "attn", False, positions, memory=memory)
-            return out, None
+            return _apply_block(xc, blk, cfg, spec, positions, memory=memory)[0], None
 
         x, _ = jax.lax.scan(body, x, params["decoder"])
-    elif cfg.family in ("hybrid", "ssm"):
+    elif cfg.period:
         period_params = params["periods"]
-        kinds = [cfg.layer_kind(pos) for pos in range(len(period_params))]
-        moes = [cfg.layer_is_moe(pos) for pos in range(len(period_params))]
+        specs = [layer_spec(cfg, pos) for pos in range(len(period_params))]
 
         if cfg.remat == "layer":
             # per-position remat: during the period backward only ONE
             # layer's intermediates are live (vs all 8 with period remat)
             def apply_pos(xc, blk, pos):
-                return _apply_block(xc, blk, cfg, kinds[pos], moes[pos], positions)[0]
+                return _apply_block(xc, blk, cfg, specs[pos], positions)[0]
 
             apply_pos = jax.checkpoint(apply_pos, static_argnums=(2,))
 
@@ -245,10 +288,21 @@ def forward(cfg: ModelConfig, params: Params, batch: dict[str, jax.Array]) -> ja
             @_maybe_remat
             def body(xc, blks):
                 for pos, blk in enumerate(blks):
-                    xc, _ = _apply_block(xc, blk, cfg, kinds[pos], moes[pos], positions)
+                    xc = _apply_block(xc, blk, cfg, specs[pos], positions)[0]
                 return xc, None
 
         x, _ = jax.lax.scan(body, x, tuple(period_params))
+    else:
+        specs, which, _ = stack_kinds(cfg)
+
+        @_maybe_remat
+        def body(xc, inp):
+            blk, w = inp
+            xc = _switch(w, [partial(lambda sp, xc: _mixer(xc, blk, cfg, sp, positions)[0], sp)
+                             for sp in specs], xc)
+            return _ffn(xc, blk, cfg, specs[0])[0], None
+
+        x, _ = jax.lax.scan(body, x, (params["layers"], jnp.asarray(which)))
     logits = _logits(cfg, params, x)
     if n_front:
         logits = logits[:, n_front:, :]
@@ -262,9 +316,10 @@ def encode(cfg: ModelConfig, params: Params, embeds: jax.Array) -> jax.Array:
     b = embeds.shape[0]
     positions = jnp.arange(embeds.shape[1], dtype=jnp.int32)[None, :].repeat(b, 0)
 
+    spec = Spec("attn", False, None, None)
+
     def body(xc, blk):
-        out, _ = _apply_block(xc, blk, cfg, "attn", False, positions, causal=False)
-        return out, None
+        return _apply_block(xc, blk, cfg, spec, positions, causal=False)[0], None
 
     x, _ = jax.lax.scan(body, embeds, params["encoder"])
     return ops.rmsnorm(x, params["enc_final_norm"], eps=cfg.norm_eps)
@@ -273,21 +328,28 @@ def encode(cfg: ModelConfig, params: Params, embeds: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # decode (serve_step)
 # ---------------------------------------------------------------------------
-def _empty_attn_cache(cfg: ModelConfig, b: int, s_max: int, dt, ring: bool) -> tuple:
+def _cache_window(cfg: ModelConfig, sp: Spec, ring: bool) -> int | None:
+    """The ring size of a layer's cache, None for a full-length one: with
+    ``ring`` every windowed layer's; without, only a declared 'sliding'
+    layer's (a uniform ``window`` then keeps a full-length cache)."""
+    if ring or "sliding" in cfg.layer_types:
+        return sp.window
+    return None
+
+
+def _empty_attn_cache(cfg: ModelConfig, b: int, s_max: int, dt, window: int | None) -> tuple:
     kv, dh = cfg.n_kv_heads, cfg.head_dim
-    # SWA archs only ever attend to the last `window` positions: with
-    # ring=True the cache is a window-sized ring buffer (the sub-quadratic
-    # long-context path); ring=False allocates the full length (serve engine
-    # prefill convenience).
-    eff = min(s_max, cfg.window) if (ring and cfg.window) else s_max
-    shape = (b, eff, kv, dh)
+    # a windowed layer only ever attends to the last `window` positions: its
+    # cache is then a window-sized ring buffer (the sub-quadratic path)
+    eff = min(s_max, window) if window else s_max
+    shape = (b, kv, eff, dh)
     return (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
 
 
-def _empty_state(cfg: ModelConfig, kind: str, b: int, s_max: int, dt, ring: bool = True):
+def _empty_state(cfg: ModelConfig, kind: str, b: int, s_max: int, dt, window=None):
     d = cfg.d_model
     if kind == "attn":
-        return _empty_attn_cache(cfg, b, s_max, dt, ring)
+        return _empty_attn_cache(cfg, b, s_max, dt, window)
     if kind == "mamba":
         din = cfg.mamba_expand * d
         return (
@@ -311,152 +373,256 @@ def _empty_state(cfg: ModelConfig, kind: str, b: int, s_max: int, dt, ring: bool
     raise ValueError(kind)
 
 
+def _stacked(n: int, st):
+    return jax.tree_util.tree_map(lambda x: jnp.zeros((n,) + x.shape, x.dtype), st)
+
+
 def init_decode_state(cfg: ModelConfig, b: int, s_max: int, ring: bool = True) -> dict:
+    """An empty decode state of batch ``b`` for ``s_max`` positions.  Each
+    layer stack leads with its layer axis, then the batch axis: ``periods``
+    (one stack per position of the period) or ``layers`` (one stack of
+    head-major KV caches (layers, B, KV, S, Dh) per attention kind of
+    ``stack_kinds``); which layers keep a window-sized ring is
+    ``_cache_window``'s rule."""
     dt = _dtype(cfg)
     state: dict[str, Any] = {"len": jnp.zeros((), jnp.int32)}
-    if cfg.family in ("dense", "moe", "vlm"):
-        st = _empty_state(cfg, "attn", b, s_max, dt, ring)
-        state["layers"] = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype), st
-        )
-    elif cfg.family == "audio":
-        st = _empty_state(cfg, "attn", b, s_max, dt, ring)
-        state["layers"] = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype), st
-        )
-        state["memory"] = jnp.zeros((b, cfg.frontend_len, cfg.d_model), dt)
-    elif cfg.family in ("hybrid", "ssm"):
-        period = cfg.attn_period or len(cfg.block_pattern)
-        n_periods = cfg.n_layers // period
-        per_pos = []
-        for pos in range(period):
-            st = _empty_state(cfg, cfg.layer_kind(pos), b, s_max, dt, ring)
-            per_pos.append(
-                jax.tree_util.tree_map(
-                    lambda x: jnp.zeros((n_periods,) + x.shape, x.dtype), st
-                )
-            )
-        state["periods"] = per_pos
+    if cfg.period:
+        n_periods = cfg.n_layers // cfg.period
+        state["periods"] = [
+            _stacked(n_periods, _empty_state(cfg, cfg.layer_kind(pos), b, s_max, dt,
+                                             _cache_window(cfg, layer_spec(cfg, pos), ring)))
+            for pos in range(cfg.period)
+        ]
+    else:
+        specs, which, _ = stack_kinds(cfg)
+        state["layers"] = [
+            _stacked(int((which == k).sum()),
+                     _empty_attn_cache(cfg, b, s_max, dt, _cache_window(cfg, sp, ring)))
+            for k, sp in enumerate(specs)
+        ]
+        if cfg.family == "audio":
+            state["memory"] = jnp.zeros((b, cfg.frontend_len, cfg.d_model), dt)
     return state
 
 
 def decode_step(
-    cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array
+    cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array, length=None
 ) -> tuple[jax.Array, dict]:
     """Decode/prefill step: tokens (B, s) -> logits (B, s, V) + new state.
 
-    s == 1 is the serve decode step; s > 1 prefills the cache (requires a
-    full-length, non-ring cache — the serve engine allocates ring=False).
+    s == 1 is the serve decode step; s > 1 prefills the cache, of which
+    ``length`` (default s) are real tokens and the rest right padding: the
+    state's ``len`` advances by ``length``, padded positions route to no
+    expert and stay out of a ring (they land past ``len`` in a full-length
+    cache, where decode overwrites them).
     """
+    logits, state, _ = decode_step_counted(cfg, params, state, tokens, length)
+    return logits, state
+
+
+def decode_step_counted(cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array,
+                        length=None) -> tuple[jax.Array, dict, jax.Array]:
+    """``decode_step`` that also returns its expert counts (2,) int32: the
+    (token, held expert) pairs computed and the held experts that received
+    a token, summed over layers."""
+    x, state, counts = _run_layers(cfg, params, state, tokens, length)
+    return _logits(cfg, params, x), state, counts
+
+
+def prefill(cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array,
+            length) -> tuple[jax.Array, dict, jax.Array]:
+    """``decode_step_counted`` of a prompt (B, s) right-padded after its
+    ``length`` real tokens, with the logits of its last real position only
+    (B, V): the head is not applied to the other positions."""
+    x, state, counts = _run_layers(cfg, params, state, tokens, length)
+    last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1, keepdims=False)
+    return _logits(cfg, params, last), state, counts
+
+
+def _run_layers(cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array, length):
+    """The layers of a decode or prefill step: (hidden states before the
+    final norm (B, s, D), new state, expert counts)."""
     b, s = tokens.shape
     x = embed_tokens(cfg, params, tokens)
     clen = state["len"]
+    n_new = s if length is None else length
     positions = clen + jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
+    valid = jnp.tile(jnp.arange(s) < n_new, b) if cfg.is_moe else None
     new_state = dict(state)
+    zero = jnp.zeros((2,), jnp.int32)
 
-    # SWA ring buffer: write slot wraps at the cache size; not-yet-written
-    # slots are masked because attn_offset caps the causal test
-    def _slots(kind: str, s_cache: int):
-        if kind != "attn":
-            return 0, 0
-        ring = cfg.window is not None and s_cache <= cfg.window
-        if ring:
-            # ring caches decode one token at a time
-            return jnp.mod(clen, s_cache), jnp.minimum(clen, s_cache - 1)
-        return clen, clen
+    if cfg.period:
+        specs = [layer_spec(cfg, pos) for pos in range(cfg.period)]
 
-    if cfg.family in ("dense", "moe", "vlm", "audio"):
-        layer_params = params["layers"] if cfg.family != "audio" else params["decoder"]
-        memory = state.get("memory")
-        is_moe = cfg.layer_is_moe(0)
-        wpos, aoff = _slots("attn", state["layers"][0].shape[2])
-
-        def body(xc, inp):
-            blk, cache = inp
-            out, new_cache = _apply_block(
-                xc, blk, cfg, "attn", is_moe, positions,
-                state=cache, write_pos=wpos, attn_offset=aoff, memory=memory,
-            )
-            return out, new_cache
-
-        x, caches = jax.lax.scan(body, x, (layer_params, state["layers"]))
-        new_state["layers"] = caches
-    else:
-        period_params = params["periods"]
-        period = len(period_params)
-        kinds = [cfg.layer_kind(pos) for pos in range(period)]
-        moes = [cfg.layer_is_moe(pos) for pos in range(period)]
-
-        def body(xc, inp):
+        def body(carry, inp):
+            xc, counts = carry
             blks, sts = inp  # tuples over positions, sliced per period
             new_sts = []
-            for pos in range(period):
-                sc = sts[pos][0].shape[1] if kinds[pos] == "attn" else 0
-                wpos, aoff = _slots(kinds[pos], sc)
-                xc, nst = _apply_block(
-                    xc, blks[pos], cfg, kinds[pos], moes[pos], positions,
-                    state=sts[pos], write_pos=wpos, attn_offset=aoff,
-                )
+            for spec, blk, st in zip(specs, blks, sts):
+                xc, nst, c = _apply_block(xc, blk, cfg, spec, positions, state=st,
+                                          clen=clen, n_new=n_new, valid=valid)
                 new_sts.append(nst)
-            return xc, tuple(new_sts)
+                counts = counts + c
+            return (xc, counts), tuple(new_sts)
 
-        x, new_per = jax.lax.scan(
-            body, x, (tuple(period_params), tuple(state["periods"]))
+        (x, counts), new_per = jax.lax.scan(
+            body, (x, zero), (tuple(params["periods"]), tuple(state["periods"]))
         )
         new_state["periods"] = list(new_per)
+    else:
+        specs, which, index = stack_kinds(cfg)
+        layer_params = params["layers"] if cfg.family != "audio" else params["decoder"]
+        memory = state.get("memory")
 
-    new_state["len"] = clen + s
-    return _logits(cfg, params, x), new_state
+        def attend(k, blk, i):
+            def branch(xc, caches):
+                ck, cv = caches[k]
+                xc, (nk, nv) = _mixer(xc, blk, cfg, specs[k], positions, memory=memory,
+                                      state=(ck[i], cv[i]), clen=clen, n_new=n_new)
+                caches = list(caches)
+                caches[k] = (ck.at[i].set(nk), cv.at[i].set(nv))
+                return xc, caches
+            return branch
+
+        def body(carry, inp):
+            xc, caches, counts = carry
+            blk, w, i = inp
+            xc, caches = _switch(w, [attend(k, blk, i) for k in range(len(specs))], xc, caches)
+            xc, c = _ffn(xc, blk, cfg, specs[0], valid)
+            return (xc, caches, counts + c), None
+
+        (x, caches, counts), _ = jax.lax.scan(
+            body, (x, list(state["layers"]), zero),
+            (layer_params, jnp.asarray(which), jnp.asarray(index)))
+        new_state["layers"] = caches
+
+    new_state["len"] = clen + n_new
+    return x, new_state, counts
 
 
 # ---------------------------------------------------------------------------
 # slot-batched decode (the continuous-batching serve path)
 # ---------------------------------------------------------------------------
+def _batch_axes(states: dict) -> dict:
+    """The batch (slot) axis of each leaf of a decode state: 0 for ``len``
+    and ``memory``, 1 for the layer stacks (their layer axis leads)."""
+    return {k: jax.tree_util.tree_map(lambda _: 0 if k in ("len", "memory") else 1, v)
+            for k, v in states.items()}
+
+
 def init_slot_states(cfg: ModelConfig, n_slots: int, s_max: int) -> dict:
-    """Decode states for ``n_slots`` independent request slots, stacked on a
-    leading slot axis (each slot is a ``b=1``, ``ring=False`` decode state
-    with its own ``len`` scalar).  The serving engine writes a freshly
-    prefilled request into one slot with ``write_slot`` while the others are
-    mid-stream."""
-    st = init_decode_state(cfg, 1, s_max, ring=False)
-    return jax.tree_util.tree_map(
-        lambda x: jnp.zeros((n_slots,) + x.shape, x.dtype), st
-    )
+    """Decode states for ``n_slots`` independent request slots: a batch of
+    ``n_slots`` decode state (``ring=False``: full-length caches, but a
+    window-sized ring for each declared sliding layer) whose ``len`` is one
+    per slot.  The serving engine writes a freshly prefilled request into
+    one slot with ``write_slot`` while the others are mid-stream."""
+    st = init_decode_state(cfg, n_slots, s_max, ring=False)
+    st["len"] = jnp.zeros((n_slots,), jnp.int32)
+    return st
 
 
 def write_slot(states: dict, i: int, state: dict) -> dict:
     """Insert a single-slot (``b=1``) decode state at slot index ``i`` of a
-    slot-stacked state tree (a refill: the new request's prefilled cache and
+    slot-batched state (a refill: the new request's prefilled cache and
     length replace whatever the finished request left behind)."""
-    return jax.tree_util.tree_map(lambda s, x: s.at[i].set(x), states, state)
+    def put(axis, s, x):
+        x = x.reshape(x.shape[:axis] + x.shape[axis + 1:]) if axis < x.ndim else x
+        return s.at[(slice(None),) * axis + (i,)].set(x)
+
+    return jax.tree_util.tree_map(put, _batch_axes(states), states, state)
 
 
 def decode_slots(
     cfg: ModelConfig, params: Params, states: dict, tokens: jax.Array
-) -> tuple[jax.Array, dict]:
+) -> tuple[jax.Array, dict, jax.Array]:
     """One decode step for every slot at once.
 
-    ``states`` is a slot-stacked tree (``init_slot_states``); ``tokens`` is
+    ``states`` is a slot-batched state (``init_slot_states``); ``tokens`` is
     ``(N,)`` int32 — the last sampled token per slot.  Returns
-    ``(logits (N, V), new states)``.  Each slot advances at its own cache
-    length / write offset (``vmap`` over the slot axis), which is what lets
-    a freshly admitted request coexist with half-finished ones without any
-    retrace: the traced shapes depend only on ``(N, s_max)``.
+    ``(logits (N, V), new states, expert counts (2,) int32)``.  Each slot
+    advances at its own cache length / write offset, which is what lets a
+    freshly admitted request coexist with half-finished ones without any
+    retrace: the traced shapes depend only on ``(N, s_max)``.  Without
+    experts (or with a period scan) the whole step is a ``vmap`` over the
+    slot axis; with experts attention stays per slot (its own length) and
+    the expert layer takes the (N, D) tokens of all slots at once, so the
+    slots share its products.
     """
+    if not cfg.is_moe or cfg.period:
+        axes = _batch_axes(states)
 
-    def one(state, tok):
-        logits, st = decode_step(cfg, params, state, tok.reshape(1, 1))
-        return logits[0, -1], st
+        def batch_of_one(state, op):  # the slot's state as a b=1 decode state, or back
+            return {k: v if k == "len" else jax.tree_util.tree_map(op, axes[k], v)
+                    for k, v in state.items()}
 
-    return jax.vmap(one)(states, tokens)
+        def one(state, tok):
+            state = batch_of_one(state, lambda a, x: jnp.expand_dims(x, a))
+            logits, st, counts = decode_step_counted(cfg, params, state, tok.reshape(1, 1))
+            return logits[0, -1], batch_of_one(st, lambda a, x: jnp.squeeze(x, a)), counts
+
+        logits, states, counts = jax.vmap(one, in_axes=(axes, 0), out_axes=(0, axes, 0))(
+            states, tokens)
+        return logits, states, counts.sum(0)
+
+    from ..kernels import ops
+
+    specs, which, index = stack_kinds(cfg)
+    clen = states["len"]  # (N,)
+    n = tokens.shape[0]
+    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+
+    def attend(k, q, kk, vv, i):
+        sp = specs[k]
+
+        def branch(caches):  # rope, the token's cache write, attention
+            ck, cv = caches[k]  # (layers of this kind, N, KV, S, Dh)
+            size = ck.shape[3]
+            ring = sp.window is not None and size <= sp.window
+            with jax.named_scope("attn.sliding" if sp.window else "attn.full"):
+                qr = L.rope(q, clen[:, None], cfg.rope_theta, sp.yarn)
+                kr = L.rope(kk, clen[:, None], cfg.rope_theta, sp.yarn)
+                wpos = jnp.mod(clen, size) if ring else clen
+                for j in range(n):  # one row per slot, written in place
+                    at = (i, j, 0, wpos[j], 0)
+                    ck = jax.lax.dynamic_update_slice(
+                        ck, kr[j, 0][None, None, :, None].astype(ck.dtype), at)
+                    cv = jax.lax.dynamic_update_slice(
+                        cv, vv[j, 0][None, None, :, None].astype(cv.dtype), at)
+                off = jnp.minimum(clen, size - 1) if ring else clen
+                o = ops.attention(qr.reshape(n * h, 1, dh), ck[i].reshape(n * kv, size, dh),
+                                  cv[i].reshape(n * kv, size, dh), causal=True,
+                                  q_offset=jnp.repeat(off, kv))
+            caches = list(caches)
+            caches[k] = (ck, cv)
+            return o.reshape(n, h * dh), caches
+        return branch
+
+    def body(carry, inp):
+        x, caches, counts = carry
+        blk, w, i = inp
+        normed = ops.rmsnorm(x, blk["norm1"], eps=cfg.norm_eps)[:, None]
+        q, kk, vv = L.project_qkv(normed, blk["mixer"], cfg)
+        o, caches = _switch(w, [attend(k, q, kk, vv, i) for k in range(len(specs))], caches)
+        x = x + o @ blk["mixer"]["wo"]
+        x, c = _ffn(x, blk, cfg, specs[0])
+        return (x, caches, counts + c), None
+
+    x = embed_tokens(cfg, params, tokens)  # (N, D)
+    (x, caches, counts), _ = jax.lax.scan(
+        body, (x, list(states["layers"]), jnp.zeros((2,), jnp.int32)),
+        (params["layers"], jnp.asarray(which), jnp.asarray(index)))
+    return _logits(cfg, params, x), dict(states, len=clen + 1, layers=caches), counts
 
 
 def decode_slots_greedy(
     cfg: ModelConfig, params: Params, states: dict, tokens: jax.Array
-) -> tuple[jax.Array, dict]:
+) -> tuple[Any, dict]:
     """``decode_slots`` with the greedy sample fused on device: returns
-    ``((N,) int32 next tokens, new states)``.  Keeping the argmax on device
-    means the sampled tokens can feed the *next* dispatched step directly —
-    the engine's pipelined dispatch only blocks on them at harvest points."""
-    logits, states = decode_slots(cfg, params, states, tokens)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), states
+    ``((N,) int32 next tokens, new states)``, and for a config with experts
+    ``((next tokens, expert counts (2,)), new states)``.  Keeping the argmax
+    on device means the sampled tokens can feed the *next* dispatched step
+    directly — the engine's pipelined dispatch only blocks on them at
+    harvest points."""
+    logits, states, counts = decode_slots(cfg, params, states, tokens)
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return ((nxt, counts) if cfg.is_moe else nxt), states

@@ -26,6 +26,14 @@ Static-shape design (TPU-friendly — no recompiles at runtime):
     only at harvest points, ``pipeline_depth`` steps behind the dispatch
     frontier.  Temperature sampling needs the logits on the host each step
     and therefore harvests synchronously.
+  * **spans** (``repro.core.spans``): ``serve.prefill`` around each
+    admission's prefill and its first token, ``serve.decode`` around each
+    batched step's dispatch.  For a config with experts each carries the
+    step's expert counters, read at the sync the engine already makes
+    (the prefill's logits, the step's harvest): ``pairs`` (token, held
+    expert) pairs computed and ``experts`` held experts that received a
+    token, summed over layers.  A decode span also carries ``lens``, the
+    cache length of each slot it ran, which the host tracks.
 
 Failure model (request-scoped — one bad request never kills the batch):
 
@@ -83,6 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..core import spans
 from ..core.database import TuningDatabase
 from ..fault import FaultInjected, FaultPlan
 from ..models import model as M
@@ -279,7 +288,7 @@ class ServingEngine:
         # re-created engines with an equal config share the functions and
         # their jax trace caches — slot refills and restarts never retrace
         self._decode = self._ctx.jitted(
-            "serve.decode", lambda: jax.jit(partial(M.decode_step, cfg)))
+            "serve.prefill", lambda: jax.jit(partial(M.prefill, cfg)))
         self._step_greedy = self._ctx.jitted(
             "serve.decode_slots_greedy",
             lambda: jax.jit(partial(M.decode_slots_greedy, cfg)))
@@ -309,9 +318,11 @@ class ServingEngine:
         self._states = M.init_slot_states(cfg, n, scfg.max_len)
         self._tokens = jnp.zeros((n,), jnp.int32)  # last sampled, per slot
         self._slots: list[RequestHandle | None] = [None] * n
+        self._lens = [0] * n  # each slot's cache length, as the host tracks it
         self._queue: deque[RequestHandle] = deque()
-        # in-flight dispatched steps: (device tokens (N,), {slot: handle})
-        self._pending: deque[tuple[Any, dict[int, RequestHandle]]] = deque()
+        # in-flight dispatched steps: (device tokens (N,) or logits, device
+        # expert counts or None, {slot: handle}, the step's span)
+        self._pending: deque[tuple[Any, Any, dict[int, RequestHandle], Any]] = deque()
         self.results: dict[int, list[int]] = {}
         self.failed: dict[int, RequestHandle] = {}
         # (program-name, from-backend, to-backend) of every compile that
@@ -414,18 +425,22 @@ class ServingEngine:
         try:
             if self.fault_plan is not None:
                 self.fault_plan.maybe_raise("serve.step")
-            if sync:
-                logits, self._states = self._dispatch_logits(
-                    self.params, self._states, self._tokens)
-                self._pending.append((logits, live))
-            else:
-                # pipelined: the sampled tokens stay on device and feed the
-                # next dispatch; the host looks at them `pipeline_depth`
-                # steps later
-                next_tok, self._states = self._dispatch_greedy(
-                    self.params, self._states, self._tokens)
-                self._tokens = next_tok
-                self._pending.append((next_tok, live))
+            with spans.span("serve.decode", slots=len(live)) as sp:
+                sp.attrs["lens"] = [self._lens[i] for i in live]
+                if sync:
+                    out, self._states, counts = self._dispatch_logits(
+                        self.params, self._states, self._tokens)
+                else:
+                    # pipelined: the sampled tokens stay on device and feed
+                    # the next dispatch; the host looks at them
+                    # `pipeline_depth` steps later
+                    out, self._states = self._dispatch_greedy(
+                        self.params, self._states, self._tokens)
+                    out, counts = out if self.cfg.is_moe else (out, None)
+                    self._tokens = out
+            self._pending.append((out, counts if self.cfg.is_moe else None, live, sp))
+            for i in live:
+                self._lens[i] += 1
         except Exception as e:  # noqa: BLE001 — batch-level dispatch failure
             # the whole dispatched step is lost: fail the requests that
             # occupied slots, recycle them, and keep the engine serviceable
@@ -572,13 +587,14 @@ class ServingEngine:
             pfn, _plan = daisy.compile(prog, jit=False)
 
             def stepfn(params, states, tokens):
-                logits, states = M.decode_slots(cfg, params, states, tokens)
+                logits, states, counts = M.decode_slots(cfg, params, states, tokens)
                 env = dict(aux)
                 env["X"] = logits.T  # (N, V) -> vocab-major (V, N)
                 out = pfn(env)["Y"]
                 if sample_greedy:
-                    return jnp.argmax(out, axis=0).astype(jnp.int32), states
-                return out.T, states  # back to (N, V) for host sampling
+                    nxt = jnp.argmax(out, axis=0).astype(jnp.int32)
+                    return ((nxt, counts) if cfg.is_moe else nxt), states
+                return out.T, states, counts  # back to (N, V) for host sampling
 
             return jax.jit(stepfn)
 
@@ -600,7 +616,7 @@ class ServingEngine:
 
     def _prefill(self, h: RequestHandle):
         """Bucket-padded prefill of one request into a fresh b=1 state;
-        returns (last-valid-position logits (V,), state)."""
+        returns (last-valid-position logits (V,), state, expert counts)."""
         cfg, scfg = self.cfg, self.scfg
         s = int(h.prompt.size)
         bucket = self._bucket_for(s)
@@ -611,11 +627,11 @@ class ServingEngine:
             # stub frontend: encoder memory from pseudo frame embeddings
             emb = jnp.zeros((1, cfg.frontend_len, cfg.d_model), M._dtype(cfg))
             state["memory"] = M.encode(cfg, self.params, emb)
-        logits, state = self._decode(self.params, state, jnp.asarray(toks))
-        # reset to the true length: the padded cache rows beyond it are
-        # causally masked and get overwritten as decode proceeds
-        state["len"] = jnp.asarray(s, jnp.int32)
-        return logits[0, s - 1], state
+        # the state advances by the true length: padded cache rows beyond it
+        # are causally masked and get overwritten as decode proceeds
+        logits, state, counts = self._decode(self.params, state, jnp.asarray(toks),
+                                             jnp.asarray(s, jnp.int32))
+        return logits[0], state, counts
 
     def _sample_from(self, lf: np.ndarray) -> int:
         if self.scfg.temperature <= 0.0:
@@ -682,8 +698,11 @@ class ServingEngine:
             try:
                 fault = None if self.fault_plan is None else \
                     self.fault_plan.maybe_raise("serve.prefill", key=h.rid)
-                last_logits, state = self._prefill(h)
-                lf = np.asarray(last_logits, np.float32)
+                with spans.span("serve.prefill", tokens=int(h.prompt.size)) as sp:
+                    last_logits, state, counts = self._prefill(h)
+                    lf = np.asarray(last_logits, np.float32)
+                    if self.cfg.is_moe:
+                        self._count(sp, counts)
                 if fault is not None and fault.kind == "nan":
                     lf = np.full_like(lf, np.nan)
                 self._check_finite(lf, h)
@@ -698,8 +717,16 @@ class ServingEngine:
                 continue
             i = self._slots.index(None)
             self._slots[i] = h
+            self._lens[i] = int(h.prompt.size)
             self._states = M.write_slot(self._states, i, state)
             self._tokens = self._tokens.at[i].set(t0)
+
+    @staticmethod
+    def _count(sp: spans.Span, counts) -> None:
+        """Put a step's expert counters, already on the host's side of a
+        sync, on its span."""
+        pairs, experts = (int(c) for c in np.asarray(counts))
+        sp.attrs.update(pairs=pairs, experts=experts)
 
     def _harvest_one(self) -> None:
         """Materialize the oldest in-flight step's tokens and credit them to
@@ -709,8 +736,10 @@ class ServingEngine:
         raised per-request work fails here, and a failed/overdue request
         frees its slot while every other slot's tokens are credited
         untouched."""
-        out, live = self._pending.popleft()
+        out, counts, live, sp = self._pending.popleft()
         arr = np.asarray(out)  # blocks until this step's results are ready
+        if counts is not None:
+            self._count(sp, counts)
         now = time.monotonic()
         for i, h in live.items():
             if h.done:  # finished in a younger harvest; overshoot dropped

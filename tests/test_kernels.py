@@ -6,7 +6,6 @@ import jax.numpy as jnp
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import gemm
-from repro.kernels.moe_gmm import grouped_matmul
 from repro.kernels.rmsnorm import rmsnorm
 
 RNG = np.random.default_rng(42)
@@ -55,16 +54,6 @@ def test_flash_attention_sweep(bh, bkv, sq, skv, causal, window, off):
     want = ref.attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                          causal=causal, window=window, q_offset=off)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
-
-
-@pytest.mark.parametrize("e,c,d,f", [(4, 16, 32, 24), (8, 10, 20, 12), (2, 128, 64, 64)])
-def test_grouped_matmul_sweep(e, c, d, f):
-    x = RNG.normal(size=(e, c, d)).astype(np.float32)
-    w = RNG.normal(size=(e, d, f)).astype(np.float32)
-    got = grouped_matmul(x, w, block_c=16, block_f=16, block_d=16, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref.grouped_matmul(x, w)), rtol=2e-4, atol=2e-4
-    )
 
 
 @pytest.mark.parametrize("r,d", [(8, 64), (100, 96), (256, 128), (5, 32)])

@@ -157,14 +157,14 @@ class TestBatchStateSpecs:
         assert specs["tokens"] == P(None, None)
 
     def test_kv_cache_dp_plus_model(self, spec_passthrough):
-        # (L, B, S, KV, dh): batch -> data, a divisible feature dim -> model
-        specs = state_specs(None, MESH, {"kv": Leaf(2, 8, 64, 4, 32)})
-        assert specs["kv"] == P(None, ("data",), None, "model", None)
+        # (L, B, KV, S, dh): batch -> data, the KV heads -> model
+        specs = state_specs(None, MESH, {"kv": Leaf(2, 8, 4, 64, 32)})
+        assert specs["kv"] == P(None, ("data",), "model", None, None)
 
     def test_kv_cache_sp_fallback_batch1(self, spec_passthrough):
         # batch=1 long-context decode: shard the cache *sequence* over DP
-        specs = state_specs(None, MESH, {"kv": Leaf(2, 1, 64, 4, 32)})
-        assert specs["kv"] == P(None, None, ("data",), "model", None)
+        specs = state_specs(None, MESH, {"kv": Leaf(2, 1, 4, 64, 32)})
+        assert specs["kv"] == P(None, None, "model", ("data",), None)
 
     def test_memory_state(self, spec_passthrough):
         specs = state_specs(None, MESH, {"memory": Leaf(8, 77, 256)})

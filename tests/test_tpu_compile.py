@@ -18,7 +18,6 @@ from repro.cloudsc import mini_cloudsc_program
 from repro.core import Schedule, compile_jax, normalize
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import gemm
-from repro.kernels.moe_gmm import grouped_matmul
 from repro.kernels.rmsnorm import rmsnorm
 from repro.polybench import BENCHMARKS
 
@@ -78,11 +77,20 @@ def test_rmsnorm_4096x3840(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_grouped_matmul_mixtral_expert(one_chip):
-    # Mixtral-8x7B: 8 experts, d_model 4096 -> d_ff 14336, 512-row buckets
-    c = _compile(partial(grouped_matmul, interpret=False), one_chip,
-                 _sds((8, 512, 4096)), _sds((8, 4096, 14336)))
-    assert "tpu_custom_call" in c.as_text()
+@pytest.mark.parametrize("tokens", [12, 8192])
+def test_expert_layer_mellum2_held_experts(one_chip, tokens):
+    # Mellum2: the 16 held of 64 experts, d_model 2304 -> 896, top-8; a
+    # decode step of 12 slots and an 8192-token prefill: the grouped
+    # products compile to the TPU's ragged-dot kernel
+    from repro.configs import get_config
+    from repro.models.layers import moe_ffn
+
+    cfg = get_config("mellum2-12b-a2.5b")
+    e, d, f = cfg.held_experts, cfg.d_model, cfg.d_ff
+    p = {"router": _sds((d, cfg.n_experts), jnp.float32), "wg": _sds((e, d, f)),
+         "wu": _sds((e, d, f)), "wd": _sds((e, f, d))}
+    c = _compile(lambda x, p: moe_ffn(x, p, cfg), one_chip, _sds((tokens, d)), p)
+    assert "ragged-dot" in c.as_text() and "tpu_custom_call" in c.as_text()
 
 
 @pytest.mark.parametrize("name, prog", [
