@@ -1,7 +1,6 @@
 """Work counts of a served decoder with per-layer attention kinds and an
 expert layer over the experts held here (``mellum2-12b-a2.5b``), read from
-the engine's own spans, and the device time of its expert product in the
-trace.
+the engine's own spans.
 
 The traced round's steps are the last ``record["traced"]["decode_steps"]``
 ``serve.decode`` spans the engine recorded (the round ends the run's
@@ -12,23 +11,16 @@ received a token; both summed over layers) and a decode span the cache
 length of each slot it ran (``lens``).  A program without those spans or
 counters reads as None.
 
-Counts, all lower bounds on what any implementation must do:
-
-* expert work of a step: ``pairs`` times three products of ``d_model`` by
-  ``d_ff`` (two operations per multiply-add); bytes: the three matrices of
-  every held expert that received a token, read once, and each pair's token
-  row read and its output row written;
-* model operations of a token at position ``p``: the four attention
-  projections of every layer, the head, and scores and weighted sums over
-  the keys it sees (``min(p + 1, window)`` on a sliding layer, ``p + 1`` on
-  a full one); the expert layer's from ``pairs``.
+Model operations, a lower bound on what any implementation must do: of a
+token at position ``p``, the four attention projections of every layer, the
+head, and scores and weighted sums over the keys it sees (``min(p + 1,
+window)`` on a sliding layer, ``p + 1`` on a full one); of a step's expert
+layer, ``pairs`` times three products of ``d_model`` by ``d_ff`` (two
+operations per multiply-add).
 """
 from __future__ import annotations
 
 from bench.program_spans import spans
-
-ITEMSIZE = {"float32": 4, "bfloat16": 2, "float16": 2}
-EXPERT_OP = "ragged-dot"  # the HLO name of the grouped product's ops
 
 
 def round_spans(rec: dict) -> tuple[list, list] | None:
@@ -46,17 +38,6 @@ def round_spans(rec: dict) -> tuple[list, list] | None:
 
 def expert_flops(m: dict, pairs: int) -> int:
     return pairs * 3 * 2 * m["d_model"] * m["d_ff"]
-
-
-def expert_bytes(m: dict, pairs: int, experts: int) -> int:
-    size = ITEMSIZE[m["dtype"]]
-    return (experts * 3 * m["d_model"] * m["d_ff"] + pairs * 2 * m["d_model"]) * size
-
-
-def expert_least_seconds(m: dict, pairs: int, experts: int, peak: dict) -> float:
-    """Least time of one step's expert work."""
-    return max(expert_bytes(m, pairs, experts) / peak["bytes_per_s"],
-               expert_flops(m, pairs) / peak["flops_per_s"])
 
 
 def kinds(m: dict) -> list[str]:
@@ -83,15 +64,3 @@ def round_flops(m: dict, decode: list, prefill: list) -> int:
         total += sum(token_flops(m, p) for p in range(s.attrs["tokens"]))
         total += expert_flops(m, s.attrs["pairs"])
     return total
-
-
-def expert_op_seconds(rec: dict) -> float | None:
-    """Device seconds of the expert product's ops in the decode module, as
-    the trace ranks them (``nested_ops``, enclosed ops counted)."""
-    tr, t = rec.get("trace"), rec.get("traced")
-    if not tr or not t or not t.get("decode_module"):
-        return None
-    module = t["decode_module"][0]
-    found = [sec for name, sec in tr["nested_ops"]
-             if name.startswith(module + ":") and EXPERT_OP in name.split(":", 1)[1]]
-    return sum(found) if found else None

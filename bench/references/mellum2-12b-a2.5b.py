@@ -22,10 +22,11 @@ experts, those held here (``0 .. experts_held - 1``) each add ``gate *
 them).  No QK normalization and no MTP module: the configuration declares
 neither.
 
-It takes the served parameter arrays as they are (the layer stack under
-``layers``, layer ``l`` at index ``l``: ``norm1``, ``mixer`` ``wq wk wv
-wo``, ``norm2``, ``ffn`` ``router wg wu wd``, the expert weights of the
-held experts only; ``embed``, ``final_norm``, ``lm_head``), upcasts them
+It takes the served parameter arrays as they are (one stack per position
+of the ``layer_types`` period under ``periods``, layer ``l`` at index ``l //
+period`` of stack ``l % period``: ``norm1``, ``mixer`` ``wq wk wv wo``,
+``norm2``, ``ffn`` ``router wg wu wd``, the expert weights of the held
+experts only; ``embed``, ``final_norm``, ``lm_head``), upcasts them
 one layer at a time, computes attention by blocks of query rows and one
 key-value head at a time and the head by blocks of the vocabulary, so that
 it fits on the chip beside the served model at 9216 positions.
@@ -196,8 +197,9 @@ def reference(params, tokens, model: dict, *, positions=None, window=_CONFIGURED
     kinds = list(model["layer_types"])
     x = params["embed"][tokens].astype(jnp.float32)
     for layer in range(model["n_layers"]):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        sliding = kinds[layer % len(kinds)] == "sliding"
+        period, at = divmod(layer, len(kinds))
+        lp = jax.tree_util.tree_map(lambda a: a[period], params["periods"][at])
+        sliding = kinds[at] == "sliding"
         x = _layer(x, lp, pos, shape=shape, window=window if sliding else None,
                    yarn=None if sliding else yarn_params, quant=quant,
                    drop_expert=drop_expert, renormalize=renormalize)

@@ -92,6 +92,8 @@ class ModelConfig:
             raise ValueError("layer_types gives the attention kinds of an all-attention stack")
         if "sliding" in self.layer_types and not self.window:
             raise ValueError("a 'sliding' layer needs a window")
+        if self.layer_types and self.n_layers % len(self.layer_types):
+            raise ValueError("n_layers is not a whole number of layer_types periods")
         if not 0 <= self.experts_held <= self.n_experts:
             raise ValueError(f"experts_held {self.experts_held} of {self.n_experts}")
 
@@ -128,13 +130,14 @@ class ModelConfig:
 
     @property
     def period(self) -> int:
-        """Layers of the repeating pattern of parameter structures the layer
-        stack scans over, one parameter stack per position (0: every layer
-        has layer 0's parameters, one stack; attention kinds of
-        ``layer_types`` differ in cache and rotary embedding only)."""
+        """Layers of the repeating pattern the layer stack scans over, one
+        parameter stack per position: Jamba's attention period, xLSTM's
+        block pattern, or ``layer_types`` (the positions differ in window,
+        cache and rotary embedding).  0: every layer is layer 0's, one
+        stack."""
         if self.family == "hybrid":
             return self.attn_period
-        return len(self.block_pattern)
+        return len(self.block_pattern) or len(self.layer_types)
 
     def attn_kind(self, l: int) -> str | None:
         """'sliding' or 'full' for layer l, None without ``layer_types``."""

@@ -4,9 +4,9 @@ Families:
   dense / moe / vlm  — decoder-only transformer (GQA, SWA, optional QKV bias,
                        optional MoE FFN), layers run under ``lax.scan`` over
                        stacked parameters (compile once per unique layer);
-                       layers of different attention kinds (``layer_types``:
-                       sliding or full) share the stack and the scan body,
-                       which switches on each layer's kind.
+                       a repeating pattern of attention kinds
+                       (``layer_types``: sliding or full) is scanned over
+                       periods, one stack per position.
   audio              — encoder-decoder (stub frame embeddings -> encoder;
                        text decoder with cross-attention).
   hybrid (Jamba)     — periodic layer pattern (1 attention : 7 Mamba, MoE on
@@ -24,7 +24,6 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..configs.base import ModelConfig, Yarn
 from . import layers as L
@@ -127,29 +126,40 @@ def layer_spec(cfg: ModelConfig, l: int) -> Spec:
     return Spec(cfg.layer_kind(l), cfg.layer_is_moe(l), cfg.layer_window(l), cfg.layer_yarn(l))
 
 
-def stack_kinds(cfg: ModelConfig) -> tuple[list[Spec], np.ndarray, np.ndarray]:
-    """The layers of one stacked scan (every family but the period-scanned
-    ones): the distinct specs they take (one per attention kind), and per
-    layer which spec and its index among that spec's layers.  Every layer
-    shares layer 0's parameter structure (expert layer or not)."""
-    moe = cfg.layer_is_moe(0) and cfg.family != "audio"
-    specs: list[Spec] = []
-    which, index = [], []
-    for layer in range(cfg.n_layers):
-        sp = layer_spec(cfg, layer)._replace(moe=moe)
-        if sp not in specs:
-            specs.append(sp)
-        which.append(specs.index(sp))
-        index.append(which.count(which[-1]) - 1)
-    return specs, np.asarray(which, np.int32), np.asarray(index, np.int32)
+def stack_specs(cfg: ModelConfig) -> list[Spec]:
+    """The spec of each parameter stack the layers scan over: one per
+    position of the period, or, for a single stack, layer 0's (every layer
+    shares its structure)."""
+    if cfg.period:
+        return [layer_spec(cfg, pos) for pos in range(cfg.period)]
+    return [layer_spec(cfg, 0)._replace(moe=cfg.layer_is_moe(0) and cfg.family != "audio")]
 
 
-def _switch(which, branches, *operands):
-    """``branches[which](*operands)``: a ``lax.switch`` on the layer's kind
-    when the stack has several, the one branch inline otherwise."""
-    if len(branches) == 1:
-        return branches[0](*operands)
-    return jax.lax.switch(which, branches, *operands)
+def _param_stacks(cfg: ModelConfig, params: Params) -> list:
+    if cfg.period:
+        return list(params["periods"])
+    return [params["decoder" if cfg.family == "audio" else "layers"]]
+
+
+def _state_stacks(cfg: ModelConfig, state: dict) -> list:
+    return list(state["periods"]) if cfg.period else [state["layers"]]
+
+
+def _with_stacks(cfg: ModelConfig, state: dict, stacks) -> dict:
+    if cfg.period:
+        return dict(state, periods=list(stacks))
+    return dict(state, layers=stacks[0])
+
+
+def _cross(x, blk: Params, cfg: ModelConfig, positions, memory):
+    """The cross-attention sub-block of an encoder-decoder's decoder layer,
+    residual included."""
+    from ..kernels import ops
+
+    normed = ops.rmsnorm(x, blk["norm_x"], eps=cfg.norm_eps)
+    cross, _ = L.attention(normed, blk["cross"], cfg, positions=positions, causal=False,
+                           memory=memory)
+    return x + cross
 
 
 def _mixer(x, blk: Params, cfg: ModelConfig, spec: Spec, positions, causal=True,
@@ -170,12 +180,7 @@ def _mixer(x, blk: Params, cfg: ModelConfig, spec: Spec, positions, causal=True,
         # (1x ring bytes); the next column-parallel matmul all-gathers.
         x = x + (L.constrain(att, ("pod", "data"), "model", None) if sp else att)
         if memory is not None:  # cross-attention sub-block (enc-dec decoder)
-            normed_x = ops.rmsnorm(x, blk["norm_x"], eps=cfg.norm_eps)
-            cross, _ = L.attention(
-                normed_x, blk["cross"], cfg, positions=positions,
-                causal=False, memory=memory,
-            )
-            x = x + cross
+            x = _cross(x, blk, cfg, positions, memory)
     elif spec.kind == "mamba":
         out, new_state = L.mamba(normed, blk["mixer"], cfg, state=state)
         x = x + out
@@ -259,18 +264,17 @@ def forward(cfg: ModelConfig, params: Params, batch: dict[str, jax.Array]) -> ja
             return jax.checkpoint(fn, policy=policy)
         return fn
 
+    specs = stack_specs(cfg)
     if cfg.family == "audio":
         memory = encode(cfg, params, batch["embeds"])
-        spec = layer_spec(cfg, 0)._replace(moe=False)
 
         @_maybe_remat
         def body(xc, blk):
-            return _apply_block(xc, blk, cfg, spec, positions, memory=memory)[0], None
+            return _apply_block(xc, blk, cfg, specs[0], positions, memory=memory)[0], None
 
         x, _ = jax.lax.scan(body, x, params["decoder"])
     elif cfg.period:
         period_params = params["periods"]
-        specs = [layer_spec(cfg, pos) for pos in range(len(period_params))]
 
         if cfg.remat == "layer":
             # per-position remat: during the period backward only ONE
@@ -293,16 +297,11 @@ def forward(cfg: ModelConfig, params: Params, batch: dict[str, jax.Array]) -> ja
 
         x, _ = jax.lax.scan(body, x, tuple(period_params))
     else:
-        specs, which, _ = stack_kinds(cfg)
-
         @_maybe_remat
-        def body(xc, inp):
-            blk, w = inp
-            xc = _switch(w, [partial(lambda sp, xc: _mixer(xc, blk, cfg, sp, positions)[0], sp)
-                             for sp in specs], xc)
-            return _ffn(xc, blk, cfg, specs[0])[0], None
+        def body(xc, blk):
+            return _apply_block(xc, blk, cfg, specs[0], positions)[0], None
 
-        x, _ = jax.lax.scan(body, x, (params["layers"], jnp.asarray(which)))
+        x, _ = jax.lax.scan(body, x, params["layers"])
     logits = _logits(cfg, params, x)
     if n_front:
         logits = logits[:, n_front:, :]
@@ -380,28 +379,20 @@ def _stacked(n: int, st):
 def init_decode_state(cfg: ModelConfig, b: int, s_max: int, ring: bool = True) -> dict:
     """An empty decode state of batch ``b`` for ``s_max`` positions.  Each
     layer stack leads with its layer axis, then the batch axis: ``periods``
-    (one stack per position of the period) or ``layers`` (one stack of
-    head-major KV caches (layers, B, KV, S, Dh) per attention kind of
-    ``stack_kinds``); which layers keep a window-sized ring is
-    ``_cache_window``'s rule."""
+    (one stack per position of the period) or ``layers`` (head-major KV
+    caches (layers, B, KV, S, Dh)); which layers keep a window-sized ring is
+    ``_cache_window``'s rule.  ``counts`` (2,) int32 are the expert counts
+    of the step that made the state."""
     dt = _dtype(cfg)
-    state: dict[str, Any] = {"len": jnp.zeros((), jnp.int32)}
-    if cfg.period:
-        n_periods = cfg.n_layers // cfg.period
-        state["periods"] = [
-            _stacked(n_periods, _empty_state(cfg, cfg.layer_kind(pos), b, s_max, dt,
-                                             _cache_window(cfg, layer_spec(cfg, pos), ring)))
-            for pos in range(cfg.period)
-        ]
-    else:
-        specs, which, _ = stack_kinds(cfg)
-        state["layers"] = [
-            _stacked(int((which == k).sum()),
-                     _empty_attn_cache(cfg, b, s_max, dt, _cache_window(cfg, sp, ring)))
-            for k, sp in enumerate(specs)
-        ]
-        if cfg.family == "audio":
-            state["memory"] = jnp.zeros((b, cfg.frontend_len, cfg.d_model), dt)
+    n_stack = cfg.n_layers // cfg.period if cfg.period else cfg.n_layers
+    stacks = [
+        _stacked(n_stack, _empty_state(cfg, sp.kind, b, s_max, dt, _cache_window(cfg, sp, ring)))
+        for sp in stack_specs(cfg)
+    ]
+    state = _with_stacks(cfg, {"len": jnp.zeros((), jnp.int32),
+                               "counts": jnp.zeros((2,), jnp.int32)}, stacks)
+    if cfg.family == "audio":
+        state["memory"] = jnp.zeros((b, cfg.frontend_len, cfg.d_model), dt)
     return state
 
 
@@ -410,106 +401,60 @@ def decode_step(
 ) -> tuple[jax.Array, dict]:
     """Decode/prefill step: tokens (B, s) -> logits (B, s, V) + new state.
 
-    s == 1 is the serve decode step; s > 1 prefills the cache, of which
-    ``length`` (default s) are real tokens and the rest right padding: the
-    state's ``len`` advances by ``length``, padded positions route to no
-    expert and stay out of a ring (they land past ``len`` in a full-length
-    cache, where decode overwrites them).
+    s == 1 is a decode step; s > 1 prefills the cache, of which ``length``
+    (default s) are real tokens and the rest right padding: the state's
+    ``len`` advances by ``length``, padded positions route to no expert and
+    stay out of a ring (they land past ``len`` in a full-length cache,
+    where decode overwrites them).  The new state's ``counts`` are the
+    step's expert counts: the (token, held expert) pairs computed and the
+    held experts that received a token, summed over layers.
     """
-    logits, state, _ = decode_step_counted(cfg, params, state, tokens, length)
-    return logits, state
-
-
-def decode_step_counted(cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array,
-                        length=None) -> tuple[jax.Array, dict, jax.Array]:
-    """``decode_step`` that also returns its expert counts (2,) int32: the
-    (token, held expert) pairs computed and the held experts that received
-    a token, summed over layers."""
-    x, state, counts = _run_layers(cfg, params, state, tokens, length)
-    return _logits(cfg, params, x), state, counts
+    x, state = _run_layers(cfg, params, state, tokens, length)
+    return _logits(cfg, params, x), state
 
 
 def prefill(cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array,
-            length) -> tuple[jax.Array, dict, jax.Array]:
-    """``decode_step_counted`` of a prompt (B, s) right-padded after its
-    ``length`` real tokens, with the logits of its last real position only
-    (B, V): the head is not applied to the other positions."""
-    x, state, counts = _run_layers(cfg, params, state, tokens, length)
+            length) -> tuple[jax.Array, dict]:
+    """``decode_step`` of a prompt (B, s) right-padded after its ``length``
+    real tokens, with the logits of its last real position only (B, V):
+    the head is not applied to the other positions."""
+    x, state = _run_layers(cfg, params, state, tokens, length)
     last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1, keepdims=False)
-    return _logits(cfg, params, last), state, counts
+    return _logits(cfg, params, last), state
 
 
 def _run_layers(cfg: ModelConfig, params: Params, state: dict, tokens: jax.Array, length):
     """The layers of a decode or prefill step: (hidden states before the
-    final norm (B, s, D), new state, expert counts)."""
+    final norm (B, s, D), new state)."""
     b, s = tokens.shape
     x = embed_tokens(cfg, params, tokens)
     clen = state["len"]
     n_new = s if length is None else length
     positions = clen + jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
     valid = jnp.tile(jnp.arange(s) < n_new, b) if cfg.is_moe else None
-    new_state = dict(state)
-    zero = jnp.zeros((2,), jnp.int32)
+    specs = stack_specs(cfg)
+    memory = state.get("memory")
 
-    if cfg.period:
-        specs = [layer_spec(cfg, pos) for pos in range(cfg.period)]
+    def body(carry, inp):
+        xc, counts = carry
+        blks, sts = inp  # tuples over the stacks, sliced per layer (period)
+        new_sts = []
+        for spec, blk, st in zip(specs, blks, sts):
+            xc, nst, c = _apply_block(xc, blk, cfg, spec, positions, memory=memory, state=st,
+                                      clen=clen, n_new=n_new, valid=valid)
+            new_sts.append(nst)
+            counts = counts + c
+        return (xc, counts), tuple(new_sts)
 
-        def body(carry, inp):
-            xc, counts = carry
-            blks, sts = inp  # tuples over positions, sliced per period
-            new_sts = []
-            for spec, blk, st in zip(specs, blks, sts):
-                xc, nst, c = _apply_block(xc, blk, cfg, spec, positions, state=st,
-                                          clen=clen, n_new=n_new, valid=valid)
-                new_sts.append(nst)
-                counts = counts + c
-            return (xc, counts), tuple(new_sts)
-
-        (x, counts), new_per = jax.lax.scan(
-            body, (x, zero), (tuple(params["periods"]), tuple(state["periods"]))
-        )
-        new_state["periods"] = list(new_per)
-    else:
-        specs, which, index = stack_kinds(cfg)
-        layer_params = params["layers"] if cfg.family != "audio" else params["decoder"]
-        memory = state.get("memory")
-
-        def attend(k, blk, i):
-            def branch(xc, caches):
-                ck, cv = caches[k]
-                xc, (nk, nv) = _mixer(xc, blk, cfg, specs[k], positions, memory=memory,
-                                      state=(ck[i], cv[i]), clen=clen, n_new=n_new)
-                caches = list(caches)
-                caches[k] = (ck.at[i].set(nk), cv.at[i].set(nv))
-                return xc, caches
-            return branch
-
-        def body(carry, inp):
-            xc, caches, counts = carry
-            blk, w, i = inp
-            xc, caches = _switch(w, [attend(k, blk, i) for k in range(len(specs))], xc, caches)
-            xc, c = _ffn(xc, blk, cfg, specs[0], valid)
-            return (xc, caches, counts + c), None
-
-        (x, caches, counts), _ = jax.lax.scan(
-            body, (x, list(state["layers"]), zero),
-            (layer_params, jnp.asarray(which), jnp.asarray(index)))
-        new_state["layers"] = caches
-
-    new_state["len"] = clen + n_new
-    return x, new_state, counts
+    (x, counts), new = jax.lax.scan(
+        body, (x, jnp.zeros((2,), jnp.int32)),
+        (tuple(_param_stacks(cfg, params)), tuple(_state_stacks(cfg, state))))
+    return x, dict(_with_stacks(cfg, state, new), len=clen + n_new, counts=counts)
 
 
 # ---------------------------------------------------------------------------
 # slot-batched decode (the continuous-batching serve path)
 # ---------------------------------------------------------------------------
-def _batch_axes(states: dict) -> dict:
-    """The batch (slot) axis of each leaf of a decode state: 0 for ``len``
-    and ``memory``, 1 for the layer stacks (their layer axis leads)."""
-    return {k: jax.tree_util.tree_map(lambda _: 0 if k in ("len", "memory") else 1, v)
-            for k, v in states.items()}
-
-
 def init_slot_states(cfg: ModelConfig, n_slots: int, s_max: int) -> dict:
     """Decode states for ``n_slots`` independent request slots: a batch of
     ``n_slots`` decode state (``ring=False``: full-length caches, but a
@@ -524,105 +469,107 @@ def init_slot_states(cfg: ModelConfig, n_slots: int, s_max: int) -> dict:
 def write_slot(states: dict, i: int, state: dict) -> dict:
     """Insert a single-slot (``b=1``) decode state at slot index ``i`` of a
     slot-batched state (a refill: the new request's prefilled cache and
-    length replace whatever the finished request left behind)."""
-    def put(axis, s, x):
-        x = x.reshape(x.shape[:axis] + x.shape[axis + 1:]) if axis < x.ndim else x
-        return s.at[(slice(None),) * axis + (i,)].set(x)
+    length replace whatever the finished request left behind).  The slot
+    axis is 0 for ``len`` and ``memory`` and 1 in the layer stacks (their
+    layer axis leads); the batch's ``counts`` stay."""
+    def put(axis):
+        def f(s, x):
+            x = jnp.squeeze(x, axis) if x.ndim == s.ndim else x
+            return s.at[(slice(None),) * axis + (i,)].set(x)
+        return f
 
-    return jax.tree_util.tree_map(put, _batch_axes(states), states, state)
+    return {k: v if k == "counts" else
+            jax.tree_util.tree_map(put(0 if k in ("len", "memory") else 1), v, state[k])
+            for k, v in states.items()}
+
+
+def _slot_attention(x, p: Params, cfg: ModelConfig, spec: Spec, cache: tuple, i, clen):
+    """Self-attention of one token per slot, x (N, 1, D), over the caches of
+    stack index ``i`` in a stack (layers, N, KV, S, Dh), each slot at its
+    own length ``clen`` (N,): its key roped at its position and its value
+    written into its own row (a ring's slot ``clen % S``) in place.
+    Returns (output (N, 1, D), the stack's caches)."""
+    from ..kernels import ops
+
+    n = x.shape[0]
+    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    ck, cv = cache
+    size = ck.shape[3]
+    ring = spec.window is not None and size <= spec.window
+    with jax.named_scope("attn.sliding" if spec.window else "attn.full"):
+        q, k, v = L.project_qkv(x, p, cfg)
+        q = L.rope(q, clen[:, None], cfg.rope_theta, spec.yarn)
+        k = L.rope(k, clen[:, None], cfg.rope_theta, spec.yarn)
+        wpos = jnp.mod(clen, size) if ring else clen
+        for j in range(n):
+            at = (i, j, 0, wpos[j], 0)
+            ck = jax.lax.dynamic_update_slice(ck, k[j, 0][None, None, :, None].astype(ck.dtype), at)
+            cv = jax.lax.dynamic_update_slice(cv, v[j, 0][None, None, :, None].astype(cv.dtype), at)
+        off = jnp.minimum(clen, size - 1) if ring else clen
+        o = ops.attention(q.reshape(n * h, 1, dh), ck[i].reshape(n * kv, size, dh),
+                          cv[i].reshape(n * kv, size, dh), causal=True,
+                          q_offset=jnp.repeat(off, kv))
+    return o.reshape(n, 1, h * dh) @ p["wo"], (ck, cv)
 
 
 def decode_slots(
     cfg: ModelConfig, params: Params, states: dict, tokens: jax.Array
-) -> tuple[jax.Array, dict, jax.Array]:
+) -> tuple[jax.Array, dict]:
     """One decode step for every slot at once.
 
     ``states`` is a slot-batched state (``init_slot_states``); ``tokens`` is
-    ``(N,)`` int32 — the last sampled token per slot.  Returns
-    ``(logits (N, V), new states, expert counts (2,) int32)``.  Each slot
-    advances at its own cache length / write offset, which is what lets a
-    freshly admitted request coexist with half-finished ones without any
-    retrace: the traced shapes depend only on ``(N, s_max)``.  Without
-    experts (or with a period scan) the whole step is a ``vmap`` over the
-    slot axis; with experts attention stays per slot (its own length) and
-    the expert layer takes the (N, D) tokens of all slots at once, so the
-    slots share its products.
+    ``(N,)`` int32 — the last sampled token per slot.  Returns ``(logits
+    (N, V), new states)``, whose ``counts`` are the step's expert counts.
+    The step is one batch of N tokens: projections, FFN or expert layer,
+    recurrent mixers and head take every slot's token at once, so the slots
+    share their weight reads; attention ropes, writes and reads each slot's
+    cache at its own length, which is what lets a freshly admitted request
+    coexist with half-finished ones without any retrace: the traced shapes
+    depend only on ``(N, s_max)``.
     """
-    if not cfg.is_moe or cfg.period:
-        axes = _batch_axes(states)
-
-        def batch_of_one(state, op):  # the slot's state as a b=1 decode state, or back
-            return {k: v if k == "len" else jax.tree_util.tree_map(op, axes[k], v)
-                    for k, v in state.items()}
-
-        def one(state, tok):
-            state = batch_of_one(state, lambda a, x: jnp.expand_dims(x, a))
-            logits, st, counts = decode_step_counted(cfg, params, state, tok.reshape(1, 1))
-            return logits[0, -1], batch_of_one(st, lambda a, x: jnp.squeeze(x, a)), counts
-
-        logits, states, counts = jax.vmap(one, in_axes=(axes, 0), out_axes=(0, axes, 0))(
-            states, tokens)
-        return logits, states, counts.sum(0)
-
     from ..kernels import ops
 
-    specs, which, index = stack_kinds(cfg)
+    specs = stack_specs(cfg)
     clen = states["len"]  # (N,)
-    n = tokens.shape[0]
-    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-
-    def attend(k, q, kk, vv, i):
-        sp = specs[k]
-
-        def branch(caches):  # rope, the token's cache write, attention
-            ck, cv = caches[k]  # (layers of this kind, N, KV, S, Dh)
-            size = ck.shape[3]
-            ring = sp.window is not None and size <= sp.window
-            with jax.named_scope("attn.sliding" if sp.window else "attn.full"):
-                qr = L.rope(q, clen[:, None], cfg.rope_theta, sp.yarn)
-                kr = L.rope(kk, clen[:, None], cfg.rope_theta, sp.yarn)
-                wpos = jnp.mod(clen, size) if ring else clen
-                for j in range(n):  # one row per slot, written in place
-                    at = (i, j, 0, wpos[j], 0)
-                    ck = jax.lax.dynamic_update_slice(
-                        ck, kr[j, 0][None, None, :, None].astype(ck.dtype), at)
-                    cv = jax.lax.dynamic_update_slice(
-                        cv, vv[j, 0][None, None, :, None].astype(cv.dtype), at)
-                off = jnp.minimum(clen, size - 1) if ring else clen
-                o = ops.attention(qr.reshape(n * h, 1, dh), ck[i].reshape(n * kv, size, dh),
-                                  cv[i].reshape(n * kv, size, dh), causal=True,
-                                  q_offset=jnp.repeat(off, kv))
-            caches = list(caches)
-            caches[k] = (ck, cv)
-            return o.reshape(n, h * dh), caches
-        return branch
+    memory = states.get("memory")
+    positions = clen[:, None]
 
     def body(carry, inp):
-        x, caches, counts = carry
-        blk, w, i = inp
-        normed = ops.rmsnorm(x, blk["norm1"], eps=cfg.norm_eps)[:, None]
-        q, kk, vv = L.project_qkv(normed, blk["mixer"], cfg)
-        o, caches = _switch(w, [attend(k, q, kk, vv, i) for k in range(len(specs))], caches)
-        x = x + o @ blk["mixer"]["wo"]
-        x, c = _ffn(x, blk, cfg, specs[0])
-        return (x, caches, counts + c), None
+        x, sts, counts = carry
+        blks, i = inp
+        sts = list(sts)
+        for pos, (spec, blk) in enumerate(zip(specs, blks)):
+            if spec.kind == "attn":
+                normed = ops.rmsnorm(x, blk["norm1"], eps=cfg.norm_eps)
+                att, sts[pos] = _slot_attention(normed, blk["mixer"], cfg, spec, sts[pos], i,
+                                                clen)
+                x = x + att
+                if memory is not None:
+                    x = _cross(x, blk, cfg, positions, memory)
+            else:  # a recurrent state: the N slots are its batch
+                st = jax.tree_util.tree_map(lambda a: a[i], sts[pos])
+                x, st = _mixer(x, blk, cfg, spec, positions, state=st)
+                sts[pos] = jax.tree_util.tree_map(lambda a, b: a.at[i].set(b), sts[pos], st)
+            x, c = _ffn(x, blk, cfg, spec)
+            counts = counts + c
+        return (x, tuple(sts), counts), None
 
-    x = embed_tokens(cfg, params, tokens)  # (N, D)
-    (x, caches, counts), _ = jax.lax.scan(
-        body, (x, list(states["layers"]), jnp.zeros((2,), jnp.int32)),
-        (params["layers"], jnp.asarray(which), jnp.asarray(index)))
-    return _logits(cfg, params, x), dict(states, len=clen + 1, layers=caches), counts
+    stacks = _param_stacks(cfg, params)
+    n_stack = jax.tree_util.tree_leaves(stacks[0])[0].shape[0]
+    x = embed_tokens(cfg, params, tokens)[:, None]  # (N, 1, D)
+    (x, sts, counts), _ = jax.lax.scan(
+        body, (x, tuple(_state_stacks(cfg, states)), jnp.zeros((2,), jnp.int32)),
+        (tuple(stacks), jnp.arange(n_stack)))
+    new = dict(_with_stacks(cfg, states, sts), len=clen + 1, counts=counts)
+    return _logits(cfg, params, x[:, 0]), new
 
 
 def decode_slots_greedy(
     cfg: ModelConfig, params: Params, states: dict, tokens: jax.Array
-) -> tuple[Any, dict]:
+) -> tuple[jax.Array, dict]:
     """``decode_slots`` with the greedy sample fused on device: returns
-    ``((N,) int32 next tokens, new states)``, and for a config with experts
-    ``((next tokens, expert counts (2,)), new states)``.  Keeping the argmax
-    on device means the sampled tokens can feed the *next* dispatched step
-    directly — the engine's pipelined dispatch only blocks on them at
-    harvest points."""
-    logits, states, counts = decode_slots(cfg, params, states, tokens)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return ((nxt, counts) if cfg.is_moe else nxt), states
+    ``((N,) int32 next tokens, new states)``.  Keeping the argmax on device
+    means the sampled tokens can feed the *next* dispatched step directly —
+    the engine's pipelined dispatch only blocks on them at harvest points."""
+    logits, states = decode_slots(cfg, params, states, tokens)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), states

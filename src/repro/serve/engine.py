@@ -28,11 +28,11 @@ Static-shape design (TPU-friendly — no recompiles at runtime):
     and therefore harvests synchronously.
   * **spans** (``repro.core.spans``): ``serve.prefill`` around each
     admission's prefill and its first token, ``serve.decode`` around each
-    batched step's dispatch.  For a config with experts each carries the
-    step's expert counters, read at the sync the engine already makes
-    (the prefill's logits, the step's harvest): ``pairs`` (token, held
-    expert) pairs computed and ``experts`` held experts that received a
-    token, summed over layers.  A decode span also carries ``lens``, the
+    batched step's dispatch.  Each carries the step's expert counters (the
+    new state's ``counts``, zero without experts), read at the sync the
+    engine already makes (the prefill's logits, the step's harvest):
+    ``pairs`` (token, held expert) pairs computed and ``experts`` held
+    experts that received a token, summed over layers.  A decode span also carries ``lens``, the
     cache length of each slot it ran, which the host tracks.
 
 Failure model (request-scoped — one bad request never kills the batch):
@@ -321,7 +321,7 @@ class ServingEngine:
         self._lens = [0] * n  # each slot's cache length, as the host tracks it
         self._queue: deque[RequestHandle] = deque()
         # in-flight dispatched steps: (device tokens (N,) or logits, device
-        # expert counts or None, {slot: handle}, the step's span)
+        # expert counts (2,), {slot: handle}, the step's span)
         self._pending: deque[tuple[Any, Any, dict[int, RequestHandle], Any]] = deque()
         self.results: dict[int, list[int]] = {}
         self.failed: dict[int, RequestHandle] = {}
@@ -428,7 +428,7 @@ class ServingEngine:
             with spans.span("serve.decode", slots=len(live)) as sp:
                 sp.attrs["lens"] = [self._lens[i] for i in live]
                 if sync:
-                    out, self._states, counts = self._dispatch_logits(
+                    out, self._states = self._dispatch_logits(
                         self.params, self._states, self._tokens)
                 else:
                     # pipelined: the sampled tokens stay on device and feed
@@ -436,9 +436,8 @@ class ServingEngine:
                     # `pipeline_depth` steps later
                     out, self._states = self._dispatch_greedy(
                         self.params, self._states, self._tokens)
-                    out, counts = out if self.cfg.is_moe else (out, None)
                     self._tokens = out
-            self._pending.append((out, counts if self.cfg.is_moe else None, live, sp))
+            self._pending.append((out, self._states["counts"], live, sp))
             for i in live:
                 self._lens[i] += 1
         except Exception as e:  # noqa: BLE001 — batch-level dispatch failure
@@ -587,14 +586,13 @@ class ServingEngine:
             pfn, _plan = daisy.compile(prog, jit=False)
 
             def stepfn(params, states, tokens):
-                logits, states, counts = M.decode_slots(cfg, params, states, tokens)
+                logits, states = M.decode_slots(cfg, params, states, tokens)
                 env = dict(aux)
                 env["X"] = logits.T  # (N, V) -> vocab-major (V, N)
                 out = pfn(env)["Y"]
                 if sample_greedy:
-                    nxt = jnp.argmax(out, axis=0).astype(jnp.int32)
-                    return ((nxt, counts) if cfg.is_moe else nxt), states
-                return out.T, states, counts  # back to (N, V) for host sampling
+                    return jnp.argmax(out, axis=0).astype(jnp.int32), states
+                return out.T, states  # back to (N, V) for host sampling
 
             return jax.jit(stepfn)
 
@@ -616,7 +614,7 @@ class ServingEngine:
 
     def _prefill(self, h: RequestHandle):
         """Bucket-padded prefill of one request into a fresh b=1 state;
-        returns (last-valid-position logits (V,), state, expert counts)."""
+        returns (last-valid-position logits (V,), state)."""
         cfg, scfg = self.cfg, self.scfg
         s = int(h.prompt.size)
         bucket = self._bucket_for(s)
@@ -629,9 +627,9 @@ class ServingEngine:
             state["memory"] = M.encode(cfg, self.params, emb)
         # the state advances by the true length: padded cache rows beyond it
         # are causally masked and get overwritten as decode proceeds
-        logits, state, counts = self._decode(self.params, state, jnp.asarray(toks),
-                                             jnp.asarray(s, jnp.int32))
-        return logits[0], state, counts
+        logits, state = self._decode(self.params, state, jnp.asarray(toks),
+                                     jnp.asarray(s, jnp.int32))
+        return logits[0], state
 
     def _sample_from(self, lf: np.ndarray) -> int:
         if self.scfg.temperature <= 0.0:
@@ -699,10 +697,9 @@ class ServingEngine:
                 fault = None if self.fault_plan is None else \
                     self.fault_plan.maybe_raise("serve.prefill", key=h.rid)
                 with spans.span("serve.prefill", tokens=int(h.prompt.size)) as sp:
-                    last_logits, state, counts = self._prefill(h)
+                    last_logits, state = self._prefill(h)
                     lf = np.asarray(last_logits, np.float32)
-                    if self.cfg.is_moe:
-                        self._count(sp, counts)
+                    self._count(sp, state["counts"])
                 if fault is not None and fault.kind == "nan":
                     lf = np.full_like(lf, np.nan)
                 self._check_finite(lf, h)
@@ -738,8 +735,7 @@ class ServingEngine:
         untouched."""
         out, counts, live, sp = self._pending.popleft()
         arr = np.asarray(out)  # blocks until this step's results are ready
-        if counts is not None:
-            self._count(sp, counts)
+        self._count(sp, counts)
         now = time.monotonic()
         for i, h in live.items():
             if h.done:  # finished in a younger harvest; overshoot dropped

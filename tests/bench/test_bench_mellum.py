@@ -25,7 +25,7 @@ from repro.models import layers as L
 from repro.models import model as M
 
 E2E = {"setup_s", "itl_p50_ms", "tokens_per_s"}
-PER_LAYER = {"idle_share.serve", "expert_roofline", "serve_mfu.moe"}
+PER_LAYER = {"idle_share.serve", "serve_mfu.moe"}
 
 
 @pytest.fixture(autouse=True)
@@ -90,7 +90,7 @@ def test_engine_programs_agree_with_the_reference_through_the_rings():
     m = cell.config["model"]
     rng = np.random.default_rng(4)
     seqs = [rng.integers(0, cfg.vocab, 80).astype(np.int32) for _ in range(2)]
-    prefill = jax.jit(partial(M.decode_step_counted, cfg))
+    prefill = jax.jit(partial(M.decode_step, cfg))
     step = jax.jit(partial(M.decode_slots, cfg))
     states = M.init_slot_states(cfg, 2, 128)
     got = [[], []]
@@ -98,17 +98,16 @@ def test_engine_programs_agree_with_the_reference_through_the_rings():
         toks = np.zeros((1, 64), np.int32)
         toks[0, :40] = seq[:40]
         st = M.init_decode_state(cfg, 1, 128, ring=False)
-        logits, st, _ = prefill(params, st, jnp.asarray(toks), jnp.int32(40))
-        rings, full = st["layers"]  # (layers, 1, KV, S, Dh) per kind
-        assert rings[0].shape[0] == 3 and rings[0].shape[3] == 16
-        assert full[0].shape[0] == 1 and full[0].shape[3] == 128
+        logits, st = prefill(params, st, jnp.asarray(toks), jnp.int32(40))
+        # one stack (periods, 1, KV, S, Dh) per position of the period
+        assert [k.shape[3] for k, _ in st["periods"]] == [16, 16, 16, 128]
         got[i].append(np.asarray(logits[0, :40]))
         states = M.write_slot(states, i, st)
     for t in range(40, 79):
-        logits, states, counts = step(params, states, jnp.asarray([s[t] for s in seqs]))
+        logits, states = step(params, states, jnp.asarray([s[t] for s in seqs]))
         for i in range(2):
             got[i].append(np.asarray(logits[i])[None])
-    assert int(counts[0]) > 0
+    assert int(states["counts"][0]) > 0
     with jax.default_matmul_precision("highest"):
         for i, seq in enumerate(seqs):
             ref = np.asarray(cell.reference(params, seq[:79], m))
@@ -156,7 +155,8 @@ def rebuilt(change_cfg=lambda c: c, change_params=lambda p: p):
 
 def drop_expert(params, e=1):
     p = jax.tree_util.tree_map(lambda x: x, params)
-    p["layers"]["ffn"]["wd"] = p["layers"]["ffn"]["wd"].at[:, e].set(0)
+    for stack in p["periods"]:
+        stack["ffn"]["wd"] = stack["ffn"]["wd"].at[:, e].set(0)
     return p
 
 
@@ -185,15 +185,13 @@ def test_fault_is_not_correct(fault, monkeypatch):
 
 def synthetic_trace(lo_ns, hi_ns, steps):
     """One prefill, then the decode module once per decode step in the
-    first half of the traced round, each run holding a grouped product."""
+    first half of the traced round."""
     mid = (lo_ns + hi_ns) / 2
     width = (mid - lo_ns - 4e6) / steps
     runs = [Event("jit__unknown(9)", lo_ns, lo_ns + 4e6)]
     runs += [Event("jit__unknown(7)", lo_ns + 4e6 + k * width, lo_ns + 4e6 + (k + 1) * width)
              for k in range(steps)]
     ops = [Event("%while.3 = bf16[8] while()", e.start, e.end) for e in runs]
-    ops += [Event("%ragged-dot-none.2 = bf16[8] custom-call()", e.start, e.start + width / 4)
-            for e in runs[1:]]
     spans_ = [Event("window", lo_ns, hi_ns), Event("dispatch:step", lo_ns, hi_ns)]
     return Trace({"/device:TPU:0": ops}, spans_, {"/device:TPU:0": runs})
 
@@ -227,8 +225,7 @@ def test_traced_run_reports_the_per_layer_metrics(monkeypatch, tmp_path):
     r = run(small_cell(), trace=True)
     assert r["correct"], r["checks"]
     assert set(r["metrics"]) == PER_LAYER
-    for name in ("expert_roofline", "serve_mfu.moe"):
-        assert 0 < r["metrics"][name]["value"], name
+    assert 0 < r["metrics"]["serve_mfu.moe"]["value"]
     rec = json.loads((tmp_path / f"{CELL}.{SEED}.trace1.json").read_text())["record"]
     assert rec["traced"]["decode_module"][0] == "jit__unknown(7)"
 
@@ -241,9 +238,7 @@ def test_expert_counts_on_hand_built_spans(monkeypatch):
     m = {"n_layers": 4, "d_model": 8, "n_heads": 4, "n_kv_heads": 2, "d_head": 2, "d_ff": 16,
          "vocab": 32, "window": 4, "layer_types": ["sliding", "sliding", "sliding", "full"],
          "dtype": "bfloat16"}
-    peak = {"bytes_per_s": 1.0, "flops_per_s": 1e30}
     assert expert_counts.expert_flops(m, 3) == 3 * 3 * 2 * 8 * 16
-    assert expert_counts.expert_least_seconds(m, 3, 2, peak) == (2 * 3 * 8 * 16 + 3 * 16) * 2
     proj = 8 * 4 * 2 * 2 + 8 * 2 * 2 * 2
     # position 9: three sliding layers see 4 keys, the full one 10
     assert expert_counts.token_flops(m, 9) == 2 * (4 * proj + 8 * 32) + 4 * 4 * 2 * (3 * 4 + 10)
@@ -254,21 +249,16 @@ def test_expert_counts_on_hand_built_spans(monkeypatch):
     for k, s in enumerate(recs):
         s.start_ns = k
     monkeypatch.setattr(spans, "records", lambda: recs)
-    rec = {"model": m, "peak": peak, "traced": {"decode_steps": 2,
-                                                "decode_module": ["jit__unknown(7)", 1.0, 2]},
-           "trace": {"busy_s": 2.0, "nested_ops": [["jit__unknown(7):while.3", 1.0],
-                                                   ["jit__unknown(7):ragged-dot-none", 0.5],
-                                                   ["jit__unknown(9):ragged-dot-none", 0.4]]}}
+    rec = {"model": m, "peak": {"flops_per_s": 1e30}, "traced": {"decode_steps": 2},
+           "trace": {"busy_s": 2.0}}
     decode, prefill = expert_counts.round_spans(rec)
     assert [s.attrs["pairs"] for s in decode] == [2, 4] and prefill == []
-    assert expert_counts.expert_op_seconds(rec) == 0.5
-    least = sum(expert_counts.expert_least_seconds(m, p, e, peak) for p, e in ((2, 1), (4, 2)))
-    metrics = harness.ROOT / "bench" / "metrics"
-    read = harness.load_module(metrics / "expert_roofline.py", "t_er").read
-    assert read(rec) == pytest.approx(100 * least / 0.5)
-    mfu = harness.load_module(metrics / "serve_mfu.moe.py", "t_mfu").read
     flops = expert_counts.round_flops(m, decode, prefill)
+    assert flops == (sum(expert_counts.token_flops(m, n) for n in (3, 9, 4, 10))
+                     + expert_counts.expert_flops(m, 6))
+    mfu = harness.load_module(harness.ROOT / "bench" / "metrics" / "serve_mfu.moe.py",
+                              "t_mfu").read
     assert mfu(rec) == pytest.approx(100 * flops / 2.0 / 1e30)
-    assert read(dict(rec, traced=dict(rec["traced"], decode_steps=9))) is None
+    assert mfu(dict(rec, traced={"decode_steps": 9})) is None
     monkeypatch.setattr(spans, "records", lambda: [])
-    assert read(rec) is None and mfu(rec) is None
+    assert mfu(rec) is None

@@ -99,7 +99,7 @@ class TestHandles:
 class TestBatchedDecode:
     def test_greedy_matches_per_request_loop(self, setup):
         """Continuous batching must be token-for-token identical to the old
-        per-request batch-1 loop (bucketed prefill + vmap decode are
+        per-request batch-1 loop (bucketed prefill + batched decode are
         bit-exact)."""
         cfg, params = setup
         prompts = [np.array([3, 1, 4, 1, 5], np.int32),
